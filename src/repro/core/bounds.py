@@ -27,6 +27,7 @@ relations of the paper's Fig. 3.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import Array
 
@@ -224,7 +225,7 @@ def joint_row_upper_bound(
 
     Returns [M, N] float32 upper bounds on ``sim(q_m, y_n)``.
     """
-    t = alpha @ beta.T
+    t = jnp.dot(alpha, beta.T, precision=jax.lax.Precision.HIGHEST)
     a_nsq = jnp.minimum(jnp.sum(alpha * alpha, axis=-1), 1.0)
     b_nsq = jnp.minimum(beta_nsq, 1.0)
     return ub_joint(t, a_nsq[:, None], b_nsq[None, :]) + slack

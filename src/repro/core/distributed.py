@@ -28,11 +28,11 @@ chip, single small collective per query batch); the same code runs on any
 mesh because only the flattened axis names are referenced.
 
 **Multi-host** (DESIGN.md §3.7): :func:`build_sharded_index_local` is the
-process-local variant of the build — each host builds pivots, blocks and
-interval caches over only the shard rows it owns and the global stacked
-index is assembled with ``jax.make_array_from_process_local_data``
-(behind :func:`repro.dist.compat.make_process_local_array`), so no host
-ever materializes the full datastore.  Search needs no multi-host
+process-local variant of the build — each shard's pivots, blocks and
+interval caches are built on the device that holds the shard, from only
+the rows it owns, and the global stacked index is assembled from those
+per-device pieces with ``jax.make_array_from_single_device_arrays``, so
+no host and no device ever materializes the full datastore.  Search needs no multi-host
 changes at all: the per-shard work and the τ / top-k merges already run
 as collectives inside ``shard_map``, which is topology-blind — the same
 jitted program serves one process with eight virtual devices and eight
@@ -69,7 +69,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.index import BlockIndex, build_index
 
-__all__ = ["build_sharded_index", "build_sharded_index_local",
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+__all__ = ["auto_mesh", "on_mesh", "build_sharded_index", "build_sharded_index_local",
            "local_shard_rows", "make_sharded_search", "sharded_search_local",
            "place_sharded_index", "make_sharded_mutation",
            "replicated_row_ids"]
@@ -109,8 +111,12 @@ def build_sharded_index(
     """Split ``db`` row-wise into ``n_shards`` and build one index per shard.
 
     Returns a :class:`BlockIndex` whose arrays carry a leading shard axis
-    ``[S, ...]`` — place it with ``NamedSharding(mesh, P(axis))`` so that each
-    device materializes only its own shard.  Rows pad to equal shard sizes.
+    ``[S, ...]``, stacked on the HOST (numpy leaves): place it with
+    :func:`place_sharded_index` so that each device materializes only its
+    own shard — no device ever holds the stack.  Rows pad to equal shard
+    sizes.  :func:`build_sharded_index_local` (what
+    ``SearchEngine.build(db, mesh=...)`` calls) skips the host round trip
+    and builds every shard on its own device.
     """
     db = np.asarray(db, np.float32)
     n = db.shape[0]
@@ -120,13 +126,30 @@ def build_sharded_index(
         db = np.concatenate([db, np.zeros((pad, db.shape[1]), np.float32)], 0)
     parts = []
     for s in range(n_shards):
-        parts.append(_build_shard_part(
+        part = _build_shard_part(
             db[s * per : (s + 1) * per],
             n_valid=min(per, max(0, n - s * per)), row_offset=s * per,
             n_pivots=n_pivots, block_size=block_size,
-            pivot_method=pivot_method))
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *parts)
-    return stacked
+            pivot_method=pivot_method)
+        parts.append(jax.tree.map(np.asarray, part))
+    return jax.tree.map(lambda *xs: np.stack(xs), *parts)
+
+
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis in ``AxisType.Auto``.
+
+    ``jax.make_mesh`` makes Explicit axes by default, and arrays placed on
+    such a mesh carry their sharding in their type: the per-shard ``vmap``
+    and scatter code of this module (written for Auto meshes, where GSPMD
+    propagates shardings) then fails to trace.  Every sharded entry point
+    normalizes the caller's mesh here — same devices, same axis names —
+    so any mesh a caller passes works.
+    """
+    from jax.sharding import AxisType
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def _flat_axes(mesh: Mesh, axis_names) -> tuple[str, ...]:
@@ -147,7 +170,7 @@ def local_shard_rows(n_rows: int, mesh: Mesh, axis_names=None):
     mesh axes; ownership is read off the placement sharding's own index
     map (``NamedSharding(mesh, P(axis)).devices_indices_map``), so the
     shard-id ↔ device assignment is by construction the one
-    ``place_sharded_index`` / ``make_array_from_process_local_data`` use
+    ``place_sharded_index`` / ``build_sharded_index_local`` use
     — including permuted ``axis_names`` orders, which flatten differently
     from ``mesh.devices``.  Returns ``(per, owned)`` where ``per`` is the
     global rows-per-shard (``ceil(n_rows / n_shards)``) and ``owned`` is
@@ -170,7 +193,7 @@ def local_shard_rows(n_rows: int, mesh: Mesh, axis_names=None):
 
 
 def build_sharded_index_local(
-    db_local: np.ndarray,
+    db_local: np.ndarray | Array,
     mesh: Mesh,
     *,
     global_rows: int,
@@ -185,10 +208,12 @@ def build_sharded_index_local(
     ``db_local`` holds ONLY the rows this process's shards cover — the
     concatenation, in ascending shard order, of the ``local_shard_rows``
     ranges (for the usual contiguous ownership that is one slice of the
-    logical datastore).  Every per-shard index (pivots, reorder, interval
-    caches) is built host-side from those rows alone, then the stacked
-    global :class:`BlockIndex` is assembled leaf-by-leaf with
-    ``make_array_from_process_local_data`` — each device materializes
+    logical datastore).  A device array already split by rows over the
+    mesh (the global array, when single-process) is used shard by shard
+    where it lies.  Every per-shard index (pivots, reorder, interval
+    caches) is built on the device that will hold it, from those rows
+    alone, and the stacked global :class:`BlockIndex` is assembled
+    leaf-by-leaf from the per-device pieces — each device materializes
     exactly its own shard and no host ever holds the full datastore.
 
     ``global_rows`` is the TOTAL logical row count across all hosts
@@ -201,7 +226,8 @@ def build_sharded_index_local(
     merges are collectives inside ``shard_map``); exactness never
     depended on cross-shard pivot knowledge in the first place.
     """
-    db_local = np.asarray(db_local, np.float32)
+    if not isinstance(db_local, jax.Array):
+        db_local = np.asarray(db_local, np.float32)
     axis = _flat_axes(mesh, axis_names)
     per, owned = local_shard_rows(global_rows, mesh, axis_names)
     n_shards = int(np.prod([mesh.shape[a] for a in axis]))
@@ -212,24 +238,53 @@ def build_sharded_index_local(
             f"shards {[s for s, _, _ in owned]} cover {expected} of the "
             f"{global_rows} global rows ({per} per shard across {n_shards} "
             f"shards); slice the datastore with local_shard_rows()")
-    parts, ofs = [], 0
+    mesh = auto_mesh(mesh)
+    sh = NamedSharding(mesh, P(axis))
+    device_of = {(idx[0].start or 0): dev for dev, idx
+                 in sh.devices_indices_map((n_shards,)).items()
+                 if dev.process_index == jax.process_index()}
+    shards, ofs = [], 0
     for s, start, stop in owned:
         cnt = stop - start
-        shard = db_local[ofs:ofs + cnt]
+        dev = device_of[s]
+        # each shard is built on the device that will hold it: the rows
+        # move there first, and every step of the build runs where its
+        # input lives — no device builds or stacks another's shard
+        rows = _rows_on_device(db_local, ofs, ofs + cnt, dev)
         ofs += cnt
         if cnt < per:  # trailing short shard: pad with invalid zero rows
-            shard = np.concatenate(
-                [shard, np.zeros((per - cnt, db_local.shape[1]), np.float32)])
-        parts.append(_build_shard_part(
-            shard, n_valid=cnt, row_offset=s * per, n_pivots=n_pivots,
-            block_size=block_size, pivot_method=pivot_method))
-    from repro.dist.compat import make_process_local_array
-    local = jax.tree.map(
-        lambda *xs: np.stack([np.asarray(x) for x in xs]), *parts)
-    sh = NamedSharding(mesh, P(axis))
+            rows = jnp.pad(rows, ((0, per - cnt), (0, 0)))
+        with jax.default_device(dev):
+            part = _build_shard_part(
+                rows, n_valid=cnt, row_offset=s * per, n_pivots=n_pivots,
+                block_size=block_size, pivot_method=pivot_method)
+        del rows
+        shards.append(_with_shard_axis(jax.device_put(part, dev)))
     return jax.tree.map(
-        lambda leaf: make_process_local_array(
-            sh, leaf, (n_shards,) + leaf.shape[1:]), local)
+        lambda *xs: jax.make_array_from_single_device_arrays(
+            (n_shards,) + xs[0].shape[1:], sh, list(xs)), *shards)
+
+
+#: [...] -> [1, ...] per leaf; donated, so the reshape reuses the shard's
+#: own buffers instead of copying them
+_with_shard_axis = jax.jit(lambda t: jax.tree.map(lambda x: x[None], t),
+                           donate_argnums=0)
+
+
+def _rows_on_device(db_local, start: int, stop: int, dev):
+    """Rows ``[start, stop)`` of ``db_local`` on ``dev``.
+
+    A device array already split by rows hands over the matching shard
+    without a copy; anything else is sliced and copied to ``dev``.
+    """
+    if isinstance(db_local, jax.Array):
+        for piece in db_local.addressable_shards:
+            rows = piece.index[0]
+            if (piece.device == dev and (rows.start or 0) == start
+                    and piece.data.shape[0] == stop - start):
+                return piece.data
+        return jax.device_put(db_local[start:stop], dev)
+    return jax.device_put(np.asarray(db_local[start:stop], np.float32), dev)
 
 
 def sharded_search_local(index: BlockIndex, queries: Array, k: int, axis_names,
@@ -354,6 +409,7 @@ def make_sharded_search(mesh: Mesh, axis_names: tuple[str, ...] | None = None,
     and ``tree_node_eval_frac``.
     """
     axis_names = tuple(axis_names or mesh.axis_names)
+    mesh = auto_mesh(mesh)
 
     from repro.dist.compat import shard_map
 
@@ -388,8 +444,24 @@ def make_sharded_search(mesh: Mesh, axis_names: tuple[str, ...] | None = None,
 def place_sharded_index(index: BlockIndex, mesh: Mesh, axis_names=None) -> BlockIndex:
     """Device-put a stacked index with the shard axis over the mesh axes."""
     axis_names = tuple(axis_names or mesh.axis_names)
-    sh = NamedSharding(mesh, P(axis_names))
+    sh = NamedSharding(auto_mesh(mesh), P(axis_names))
     return jax.tree.map(lambda x: jax.device_put(x, sh), index)
+
+
+def on_mesh(index: BlockIndex, mesh: Mesh) -> BlockIndex:
+    """``index`` with every leaf sharded over ``mesh`` itself.
+
+    Leaves placed on another mesh over the same devices (typically the
+    caller's Explicit-axis mesh, see :func:`auto_mesh`) are re-labelled
+    with the same partition spec; anything else passes through unchanged.
+    """
+    def move(x):
+        sh = getattr(x, "sharding", None)
+        if isinstance(sh, NamedSharding) and sh.mesh != mesh:
+            return jax.device_put(x, NamedSharding(mesh, sh.spec))
+        return x
+
+    return jax.tree.map(move, index)
 
 
 def replicated_row_ids(index: BlockIndex, mesh: Mesh) -> np.ndarray:
@@ -408,7 +480,7 @@ def replicated_row_ids(index: BlockIndex, mesh: Mesh) -> np.ndarray:
     rid = index.row_ids
     if isinstance(rid, jax.Array) and not rid.is_fully_addressable:
         rep = jax.jit(lambda x: x,
-                      out_shardings=NamedSharding(mesh, P()))(rid)
+                      out_shardings=NamedSharding(auto_mesh(mesh), P()))(rid)
         return np.asarray(rep.addressable_shards[0].data)
     return np.asarray(rid)
 
@@ -435,6 +507,7 @@ class ShardedMutationOps:
 
     def __init__(self, mesh: Mesh, axis_names=None):
         axis = _flat_axes(mesh, axis_names)
+        mesh = auto_mesh(mesh)
         self.mesh = mesh
         self.axis = axis
         self.sharding = NamedSharding(mesh, P(axis))
@@ -445,7 +518,8 @@ class ShardedMutationOps:
                 n_pad = idx.db.shape[0]
                 nb = idx.dp_min.shape[0]
                 bs = n_pad // nb
-                dp_new = rw @ idx.pivots.T                   # [R, P]
+                dp_new = jnp.dot(rw, idx.pivots.T,
+                                 precision=_HIGHEST)         # [R, P]
                 sl_s = jnp.where(mk, sl, n_pad)              # drop padding
                 blk = jnp.where(mk, sl // bs, nb)
                 new = idx._replace(
@@ -457,7 +531,7 @@ class ShardedMutationOps:
                     dp_max=idx.dp_max.at[blk].max(dp_new, mode="drop"),
                 )
                 if idx.ortho is not None:
-                    beta = rw @ idx.ortho.T
+                    beta = jnp.dot(rw, idx.ortho.T, precision=_HIGHEST)
                     bnsq = jnp.cumsum(beta * beta, axis=1)
                     new = new._replace(
                         beta=idx.beta.at[sl_s].set(beta, mode="drop"),

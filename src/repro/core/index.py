@@ -106,59 +106,86 @@ def build_index(
     with natural (shuffled) order the intervals span nearly [-1, 1] and no
     block can ever be pruned.  Search results are returned in original ids
     via ``row_ids``.
+
+    Every corpus-sized step runs on the device holding ``db`` inside a
+    jitted call, and none keeps more than one corpus-sized buffer beside
+    its input: the normalized, padded, reordered rows are produced by one
+    gather, so the build peaks near twice the corpus and the host never
+    holds a copy.
     """
-    dbn = normalize(jnp.asarray(db, jnp.float32))
-    n, d = dbn.shape
+    db = jnp.asarray(db, jnp.float32)
+    n = db.shape[0]
     # More pivots than points is degenerate-but-reachable (tiny corpora /
     # shards): clamp so selection and the joint-bound tables stay defined.
     n_pivots = max(1, min(int(n_pivots), n))
-    n_pad = -(-n // block_size) * block_size
-    pad = n_pad - n
-    dbn = jnp.pad(dbn, ((0, pad), (0, 0)))
-    valid = jnp.arange(n_pad) < n
-    row_ids = jnp.where(valid, jnp.arange(n_pad), -1).astype(jnp.int32)
-
     if pivot_method == "maxmin":
-        piv_idx = select_pivots_maxmin(dbn[:n], n_pivots)
+        piv_idx = select_pivots_maxmin(db, n_pivots)
     elif pivot_method == "random":
         piv_idx = select_pivots_random(n, n_pivots, seed)
     else:
         raise ValueError(f"unknown pivot_method {pivot_method!r}")
-    pivots = dbn[piv_idx]                      # [P, d] (already unit norm)
+    index = _build_arrays(db, piv_idx, block_size=block_size,
+                          reorder=reorder)
+    # Joint multi-pivot bound tables: the basis is a float64 host
+    # factorization of the [P, d] pivots; the [n_pad, P] coordinates are an
+    # f32 HIGHEST matmul on the *reordered* rows, so beta[i] matches db[i]
+    # (its rounding is absorbed by JOINT_SLACK).  maxmin selection is
+    # nested, so prefix slices of these tables are exactly the tables a
+    # shallower index would have built.
+    ortho = jax.device_put(
+        jnp.asarray(orthonormal_pivot_basis(index.pivots), jnp.float32),
+        index.db.sharding)
+    beta, beta_nsq = _joint_tables(index.db, ortho)
+    return index._replace(ortho=ortho, beta=beta, beta_nsq=beta_nsq)
 
-    dp = dbn @ pivots.T                        # [n_pad, P]
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@functools.partial(jax.jit, static_argnames=("block_size", "reorder"))
+def _build_arrays(db: Array, piv_idx: Array, *, block_size: int,
+                  reorder: bool) -> BlockIndex:
+    """Normalize, pad, reorder and summarize ``db`` in one device call."""
+    n = db.shape[0]
+    n_pad = -(-n // block_size) * block_size
+    # normalize()'s own arithmetic, applied row by row inside the gather
+    # below instead of to a full-size copy
+    norm = jnp.maximum(jnp.linalg.norm(db, axis=-1), 1e-12)
+    pivots = db[piv_idx] / norm[piv_idx, None]               # [P, d] unit
+    p = pivots.shape[0]
     if reorder:
-        perm = reorder_perm(dp, valid, n_pivots)
-        dbn, dp = dbn[perm], dp[perm]
-        valid, row_ids = valid[perm], row_ids[perm]
+        dp0 = jnp.dot(db, pivots.T, precision=_HIGHEST) / norm[:, None]
+        dp0 = jnp.pad(dp0, ((0, n_pad - n), (0, 0)))
+        perm = reorder_perm(dp0, jnp.arange(n_pad) < n, p)
+    else:
+        perm = jnp.arange(n_pad)
+    valid = perm < n                                         # padding: zero rows
+    dbn = (jnp.take(db, perm, axis=0, mode="fill", fill_value=0.0)
+           / jnp.take(norm, perm, mode="fill", fill_value=1.0)[:, None])
+    row_ids = jnp.where(valid, perm, -1).astype(jnp.int32)
+    # the stored similarities are those of the stored rows, at the same
+    # precision every search path scores with
+    dp = jnp.dot(dbn, pivots.T, precision=_HIGHEST)         # [n_pad, P]
     # Padding rows are zero vectors => dp = 0; exclude them from the block
     # intervals so they can't loosen the bound.
-    dp_for_min = jnp.where(valid[:, None], dp, jnp.inf)
-    dp_for_max = jnp.where(valid[:, None], dp, -jnp.inf)
     nb = n_pad // block_size
-    dp_min = dp_for_min.reshape(nb, block_size, -1).min(axis=1)
-    dp_max = dp_for_max.reshape(nb, block_size, -1).max(axis=1)
+    dp_min = jnp.where(valid[:, None], dp, jnp.inf).reshape(
+        nb, block_size, -1).min(axis=1)
+    dp_max = jnp.where(valid[:, None], dp, -jnp.inf).reshape(
+        nb, block_size, -1).max(axis=1)
     # A fully-padded block keeps the +inf/-inf identity of the masked
     # reduce: the *empty-interval sentinel*.  Every bound path maps an
     # inverted interval (lo > hi) to a -inf upper bound, so empty blocks
     # prune unconditionally, and — critically for the online path — an
     # insert's scatter-min/max against the sentinel records the new row's
     # EXACT interval instead of anchoring it at a neutral value.
+    return BlockIndex(dbn, dp, pivots, dp_min, dp_max, valid, row_ids)
 
-    # Joint multi-pivot bound tables (float64 at build, float32 stored).
-    # Computed on the *reordered* rows so beta[i] matches db[i]; maxmin
-    # selection is nested, so prefix slices of these tables are exactly the
-    # tables a shallower index would have built.
-    import numpy as np
-    u64 = orthonormal_pivot_basis(pivots)                   # [P, d] f64
-    beta64 = np.asarray(dbn, np.float64) @ u64.T            # [n_pad, P]
-    beta_nsq64 = np.cumsum(beta64 * beta64, axis=1)
-    ortho = jnp.asarray(u64, jnp.float32)
-    beta = jnp.asarray(beta64, jnp.float32)
-    beta_nsq = jnp.asarray(beta_nsq64, jnp.float32)
-    return BlockIndex(dbn, dp, pivots, dp_min, dp_max, valid, row_ids,
-                      ortho, beta, beta_nsq)
+
+@jax.jit
+def _joint_tables(dbn: Array, ortho: Array):
+    beta = jnp.dot(dbn, ortho.T, precision=_HIGHEST)         # [n_pad, P]
+    return beta, jnp.cumsum(beta * beta, axis=1)
 
 
 def reorder_perm(dp: Array, valid: Array, n_pivots: int) -> Array:
@@ -229,7 +256,8 @@ def multipivot_block_cap(index: BlockIndex, qn: Array, *, n_pivots: int) -> Arra
     if not 1 <= j <= index.bound_table_width:
         raise ValueError(
             f"n_pivots={j} outside [1, {index.bound_table_width}]")
-    alpha = qn.astype(jnp.float32) @ index.ortho[:j].T          # [M, j]
+    alpha = jnp.dot(qn.astype(jnp.float32), index.ortho[:j].T,
+                    precision=_HIGHEST)                         # [M, j]
     row_ub = joint_row_upper_bound(
         alpha, index.beta[:, :j], index.beta_nsq[:, j - 1])     # [M, n_pad]
     row_ub = jnp.where(index.valid[None, :], row_ub, -jnp.inf)
@@ -258,7 +286,7 @@ def search(*args, **kwargs):
 def search_brute(index: BlockIndex, queries: Array, k: int):
     """Brute-force exact top-k (baseline; also the correctness oracle shape)."""
     qn = normalize(jnp.asarray(queries, jnp.float32))
-    scores = qn @ index.db.T
+    scores = jnp.dot(qn, index.db.T, precision=_HIGHEST)
     scores = jnp.where(index.valid[None, :], scores, -jnp.inf)
     sims, idx = jax.lax.top_k(scores, k)
     idx = jnp.where(idx >= 0, index.row_ids[jnp.maximum(idx, 0)], -1)

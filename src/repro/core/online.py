@@ -48,6 +48,7 @@ the kNN-LM value array, :mod:`repro.serve.knnlm`) never need remapping.
 from __future__ import annotations
 
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -199,7 +200,8 @@ class MutableIndex:
         rows_n = jnp.asarray(rows64, jnp.float32)
         # same fp32 product the flat search paths compare against, so the
         # widened intervals bound exactly what the kernels compute
-        dp_new = rows_n @ index.pivots.T                     # [n_new, P]
+        dp_new = jnp.dot(rows_n, index.pivots.T,
+                         precision=jax.lax.Precision.HIGHEST)  # [n_new, P]
         new_index = index._replace(
             db=index.db.at[posj].set(rows_n),
             dp=index.dp.at[posj].set(dp_new),

@@ -9,6 +9,8 @@ cheap random fallback.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,26 +23,31 @@ def normalize(x: Array, eps: float = 1e-12) -> Array:
     return x / jnp.maximum(n, eps)
 
 
+@functools.partial(jax.jit, static_argnames=("n_pivots", "first"))
 def select_pivots_maxmin(db: Array, n_pivots: int, *, first: int = 0) -> Array:
     """Greedy farthest-first pivot selection (returns pivot *indices*).
 
     Iteratively picks the point whose maximum similarity to the already
     selected pivots is smallest (i.e. the angularly farthest point).  Runs in
-    O(n_pivots * n * d) — jit-friendly via ``lax.fori_loop``.
+    O(n_pivots * n * d) as one jitted ``lax.fori_loop`` over the rows as
+    given: each row's similarity is scaled by its inverse norm, so no
+    normalized corpus-sized copy is ever held beside the input.
 
     Args:
-      db: [n, d] database (need not be normalized; it is normalized here).
+      db: [n, d] database (need not be normalized).
       n_pivots: number of pivots to select (>= 1).
       first: index of the initial pivot (deterministic by default).
     """
-    dbn = normalize(db.astype(jnp.float32))
-    n = dbn.shape[0]
+    db = db.astype(jnp.float32)
+    n = db.shape[0]
+    inv_norm = 1.0 / jnp.maximum(jnp.linalg.norm(db, axis=-1), 1e-12)
 
     def body(i, state):
         idx, max_sim = state
         # similarity of every point to the i-1'th chosen pivot
-        prev = dbn[idx[i - 1]]
-        sims = dbn @ prev
+        prev = db[idx[i - 1]] * inv_norm[idx[i - 1]]
+        sims = jnp.dot(db, prev,
+                       precision=jax.lax.Precision.HIGHEST) * inv_norm
         max_sim = jnp.maximum(max_sim, sims)
         # next pivot: the point least similar to all chosen so far
         nxt = jnp.argmin(max_sim)

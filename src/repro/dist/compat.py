@@ -1,20 +1,13 @@
-"""JAX version compatibility shims.
+"""The jax entry points the repo routes through one module.
 
-The repo targets the modern ``jax.shard_map`` API (``check_vma`` /
-``axis_names``); older installed versions only have
-``jax.experimental.shard_map.shard_map`` (``check_rep`` / ``auto``).
-:func:`shard_map` papers over the difference so call sites stay on the
-modern spelling.
+The installed jax (0.9.0, pinned in ``pyproject.toml``) provides all of
+these natively; the wrappers stay so that every call site — and lint rule
+R002, which keeps the raw APIs out of the rest of the tree — has one place
+to change when jax moves them again.
 
 The multi-host helpers (:func:`make_process_local_array`,
 :func:`replicate_to_mesh`, :func:`multiprocess_cpu_init`) wrap the
-process-local array-assembly surface the distributed build relies on:
-``jax.make_array_from_process_local_data`` exists in jax 0.4.37 but the
-repo keeps one call site behind this shim (with a
-``make_array_from_single_device_arrays`` fallback) so a jax without it —
-or with a changed signature — only needs a fix here, and so CPU worker
-processes get the one non-obvious 0.4.37 knob
-(``jax_cpu_collectives_implementation='gloo'``) from a single place.
+process-local array-assembly surface the distributed paths rely on.
 """
 from __future__ import annotations
 
@@ -25,67 +18,27 @@ __all__ = ["shard_map", "optimization_barrier", "make_process_local_array",
            "replicate_to_mesh", "multiprocess_cpu_init"]
 
 
-@jax.custom_vjp
 def optimization_barrier(x):
-    """``jax.lax.optimization_barrier`` with a differentiation rule.
-
-    Newer JAX differentiates the barrier natively (barrier on the
-    cotangents); older versions raise NotImplementedError inside grad —
-    this wrapper supplies that same rule everywhere.
-    """
+    """``jax.lax.optimization_barrier`` (differentiable natively)."""
     return jax.lax.optimization_barrier(x)
-
-
-def _opt_barrier_fwd(x):
-    return optimization_barrier(x), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-optimization_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
               axis_names=None):
-    """``jax.shard_map`` with fallback to the experimental API.
-
-    ``axis_names`` selects the manual axes (partial-manual mode); on old
-    JAX this maps to ``auto = mesh axes - axis_names``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
+    """``jax.shard_map``; ``axis_names`` selects the manual axes
+    (partial-manual mode)."""
     kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
+              check_vma=check_vma)
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, **kw)
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, **kw)
 
 
 def multiprocess_cpu_init(coordinator_address: str, num_processes: int,
                           process_id: int) -> None:
-    """``jax.distributed.initialize`` for multi-process CPU workers.
-
-    On jax 0.4.37 the CPU client compiles multi-process programs only when
-    a cross-process collectives implementation is configured, and the knob
-    (``jax_cpu_collectives_implementation``) is an enum flag that does NOT
-    read the environment — it must be set via ``jax.config.update`` before
-    the backend is created.  Call this before any other jax API touches
-    devices.  No-op on the collectives knob when the config is absent
-    (newer jax selects a working CPU collectives impl itself).
-    """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass  # newer jax: gloo is the default / knob renamed
+    """``jax.distributed.initialize`` for multi-process CPU workers (the
+    CPU client's default cross-process collectives are gloo).  Call this
+    before any other jax API touches devices."""
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -101,52 +54,9 @@ def make_process_local_array(sharding, local_data: np.ndarray, global_shape):
     upstream function's documented mapping).  Dimensions where the local
     and global sizes match are read at global coordinates (replicated
     data must therefore be identical on every process).
-
-    jax 0.4.37 ships the upstream function; the fallback assembles the
-    same array from per-device ``device_put`` slices for a jax that
-    predates it or changes its signature.
     """
-    local_data = np.asarray(local_data)
-    global_shape = tuple(global_shape)
-    if hasattr(jax, "make_array_from_process_local_data"):
-        return jax.make_array_from_process_local_data(sharding, local_data,
-                                                      global_shape)
-    # fallback: map each addressable device's global slice into local_data
-    # coordinates (ascending-start order along shrunk dimensions)
-    index_map = sharding.devices_indices_map(global_shape)
-    addressable = [d for d in sharding.device_set
-                   if d.process_index == jax.process_index()]
-    offsets = []
-    for dim in range(len(global_shape)):
-        if local_data.shape[dim] == global_shape[dim]:
-            offsets.append(None)  # global coordinates apply directly
-        else:
-            size_at = {}
-            for d in addressable:
-                idx = index_map[d][dim]
-                start = idx.start or 0
-                stop = idx.stop if idx.stop is not None else global_shape[dim]
-                size_at[start] = stop - start
-            starts = sorted(size_at)
-            local_starts, ofs = {}, 0
-            for start in starts:
-                local_starts[start] = ofs
-                ofs += size_at[start]
-            offsets.append(local_starts)
-    shards = []
-    for d in addressable:
-        sl = []
-        for dim, idx in enumerate(index_map[d]):
-            start = idx.start or 0
-            stop = idx.stop if idx.stop is not None else global_shape[dim]
-            if offsets[dim] is not None:
-                length = stop - start
-                start = offsets[dim][start]
-                stop = start + length
-            sl.append(slice(start, stop))
-        shards.append(jax.device_put(local_data[tuple(sl)], d))
-    return jax.make_array_from_single_device_arrays(global_shape, sharding,
-                                                    shards)
+    return jax.make_array_from_process_local_data(
+        sharding, np.asarray(local_data), tuple(global_shape))
 
 
 def replicate_to_mesh(x, mesh):

@@ -10,7 +10,8 @@ hot-spot after the score matmul, and fusing the min-over-pivots avoids
 materializing the [M, NB, P] intermediate in HBM (P× traffic reduction —
 this is the memory-bound term in the roofline).
 
-Grid: (M/BM, NB/BB).  Tiles: qp [BM, P], lo/hi [BB, P], out [BM, BB].
+Grid: (M/BM, NB/BB).  Tiles: qp [BM, P], lo/hi [P, BB] (the intervals
+are transposed so blocks ride the lanes), out [BM, BB].
 """
 from __future__ import annotations
 
@@ -38,20 +39,26 @@ def _kernel_cap(qp_ref, lo_ref, hi_ref, cap_ref, out_ref):
 
 def _interval_ub(qp_ref, lo_ref, hi_ref):
     qp = qp_ref[...].astype(jnp.float32)          # [BM, P]
-    lo = lo_ref[...].astype(jnp.float32)          # [BB, P]
+    lo = lo_ref[...].astype(jnp.float32)          # [P, BB]  (blocks on lanes)
     hi = hi_ref[...].astype(jnp.float32)
-    a = qp[:, None, :]                            # [BM, 1, P]
-    l = lo[None, :, :]                            # [1, BB, P]
-    h = hi[None, :, :]
-    rad_a = jnp.maximum(0.0, 1.0 - a * a)
-    ub_l = a * l + jnp.sqrt(rad_a * jnp.maximum(0.0, 1.0 - l * l))
-    ub_h = a * h + jnp.sqrt(rad_a * jnp.maximum(0.0, 1.0 - h * h))
-    per_pivot = jnp.where((a >= l) & (a <= h), 1.0, jnp.maximum(ub_l, ub_h))
-    # inverted interval (l > h): the empty-block sentinel — bound is -inf
-    # (keeps this kernel value-identical to kref.block_bounds on indexes
-    # that carry all-padding blocks from online mutation)
-    per_pivot = jnp.where(l > h, -jnp.inf, per_pivot)
-    return per_pivot.min(axis=-1)                 # [BM, BB]
+    ub = None
+    # one [BM, BB] pass per pivot: a [BM, BB, P] intermediate would put the
+    # few pivots on the 128 lanes and overflow VMEM at deployment tiles
+    for p in range(qp.shape[1]):
+        a = qp[:, p:p + 1]                        # [BM, 1]
+        l = lo[p:p + 1, :]                        # [1, BB]
+        h = hi[p:p + 1, :]
+        rad_a = jnp.maximum(0.0, 1.0 - a * a)
+        ub_l = a * l + jnp.sqrt(rad_a * jnp.maximum(0.0, 1.0 - l * l))
+        ub_h = a * h + jnp.sqrt(rad_a * jnp.maximum(0.0, 1.0 - h * h))
+        per_pivot = jnp.where((a >= l) & (a <= h), 1.0,
+                              jnp.maximum(ub_l, ub_h))
+        # inverted interval (l > h): the empty-block sentinel — bound is
+        # -inf (keeps this kernel value-identical to kref.block_bounds on
+        # indexes that carry all-padding blocks from online mutation)
+        per_pivot = jnp.where(l > h, -jnp.inf, per_pivot)
+        ub = per_pivot if ub is None else jnp.minimum(ub, per_pivot)
+    return ub                                     # [BM, BB]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bb", "interpret"))
@@ -68,7 +75,7 @@ def block_bounds(
     """[M, P] x [NB, P] -> [M, NB] block upper bounds (f32).
 
     M and NB are padded internally to tile multiples; P stays whole (pivot
-    counts are small, 8–64, and live in the minor-most VMEM lane dim).
+    counts are small, 8–64) and is looped over inside the kernel.
 
     ``ub_cap`` [M, NB] (optional) is an extra per-(query, block) upper
     bound — the joint multi-pivot cap of DESIGN.md §3.8 — intersected with
@@ -83,12 +90,13 @@ def block_bounds(
     qp_p = jnp.pad(qp, ((0, mp - m), (0, 0)))
     # pad blocks with degenerate interval [2, 2]^c -> inside=False and
     # ub <= ... values unused (sliced off below); any finite pad is fine.
-    lo_p = jnp.pad(dp_min, ((0, nbp - nb), (0, 0)), constant_values=0.0)
-    hi_p = jnp.pad(dp_max, ((0, nbp - nb), (0, 0)), constant_values=0.0)
+    # intervals transposed to [P, NB]: blocks ride the lanes
+    lo_p = jnp.pad(dp_min, ((0, nbp - nb), (0, 0)), constant_values=0.0).T
+    hi_p = jnp.pad(dp_max, ((0, nbp - nb), (0, 0)), constant_values=0.0).T
     in_specs = [
         pl.BlockSpec((bm_, p), lambda i, j: (i, 0)),
-        pl.BlockSpec((bb_, p), lambda i, j: (j, 0)),
-        pl.BlockSpec((bb_, p), lambda i, j: (j, 0)),
+        pl.BlockSpec((p, bb_), lambda i, j: (0, j)),
+        pl.BlockSpec((p, bb_), lambda i, j: (0, j)),
     ]
     operands = [qp_p, lo_p, hi_p]
     kern = _kernel

@@ -3,26 +3,35 @@
 One kernel implements the whole search inner loop of
 :mod:`repro.core.index`:
 
-  for each query tile i (grid dim 0, parallel):
+  for each query tile i (grid dim 0):
     for each database tile j (grid dim 1, sequential):
       1. evaluate the Eq. 13 pivot-interval upper bound for tile j   (VPU)
       2. if no query in the tile can beat its running k-th best: SKIP —
          ``@pl.when`` guards the matmul and the top-k merge entirely
       3. else: scores = q_tile @ db_tile.T                           (MXU)
-         merge into the running top-k held in VMEM scratch           (VPU)
+         merge into the running top-k held in the resident output block
 
-The running (top_s, top_i) scratch persists across the sequential j steps
-(TPU grid iteration order guarantees this); outputs are flushed on the last
-j.  The merge uses K unrolled max/argmax extractions — K <= 64 keeps this a
-small fraction of the matmul cost at BN >= 256.
+The running (top_s, top_i) block persists across the sequential j steps
+(TPU grid iteration order guarantees this) and is sorted once, outside
+the kernel.  The merge keeps the k slots *unsorted*: each round moves
+every row's best remaining tile score into that row's current minimum
+slot, and the rounds stop as soon as no row's best remaining score beats
+its k-th best — after a warm τ most computed tiles need zero or one
+round, and no round allocates anything wider than ``[BM, BN]``.
 
-On real TPU hardware step 2's win is MXU + VMEM-bandwidth; the HBM->VMEM
-copy of a pruned tile can additionally be elided with a scalar-prefetch
-index map (planned variant; the copy is sequential-DMA-overlapped anyway).
-In this repo the kernel is validated with ``interpret=True`` on CPU.
+Layout (what Mosaic's (8, 128) tiling rule forced when the kernel was
+first compiled for TPU v5e): per-tile pivot intervals and row validity
+ride in 3-D arrays whose last two dims are whole (``[nt, 1, P]``,
+``[nt, 1, BN]``), the per-(query tile, db tile) ``computed`` / element
+counters are whole-array SMEM outputs written at ``[i, tile]``, the
+optional joint cap is fetched as a lane-dense ``[BM, 128]`` slab and its
+column selected in-kernel, and the score matmul runs at
+``Precision.HIGHEST`` (an f32 dot otherwise runs as one bf16 pass on the
+MXU, ~1e-3 off, which the ``margin`` cannot absorb — DESIGN.md §3.12).
+The HBM->VMEM copy of a pruned tile is not yet elided.
 
-Alignment: BM, BN multiples of 128 (MXU systolic dims); D <= 4096 is kept
-whole in VMEM (q tile + db tile at BM=BN=128, D=4096, f32 = 4 MiB of ~16).
+Alignment: BM a multiple of 8, BN a multiple of 128 on TPU; D is kept
+whole in VMEM (q tile + db tile at BM=128, BN=256, D=768, f32 = 1.1 MiB).
 """
 from __future__ import annotations
 
@@ -37,26 +46,25 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BM = 128
 DEFAULT_BN = 256
 _NEG_INF = float("-inf")
+_LANES = 128
 
 
 def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
                  element_stats: bool, use_cap: bool = False):
-    def kernel(order_ref, nvalid_ref, tau_ref, qn_ref, db_ref, qp_ref,
+    def kernel(order_ref, mvalid_ref, tau_ref, qn_ref, db_ref, qp_ref,
                lo_ref, hi_ref, rv_ref, *rest):
-        if use_cap:
-            cap_ref, rest = rest[0], rest[1:]
-        if element_stats:
-            dp_ref, top_s_out, top_i_out, computed_ref, elem_ref = rest[:5]
-            top_s, top_i = rest[5:]
-        else:
-            top_s_out, top_i_out, computed_ref = rest[:3]
-            top_s, top_i = rest[3:]
+        rest = list(rest)
+        cap_ref = rest.pop(0) if use_cap else None
+        dp_ref = rest.pop(0) if element_stats else None
+        top_s, top_i, computed_ref = rest[:3]
+        elem_ref = rest[3] if element_stats else None
+        sc_ref = rest[-1]
         i = pl.program_id(0)
         j = pl.program_id(1)
-        nj = pl.num_programs(1)
+        kp = top_s.shape[1]
         # best-first: step j of query tile i visits db tile order[i, j]
         # (the BlockSpec index maps fetched that tile; this is the global
-        # column base for id bookkeeping)
+        # tile id for id bookkeeping and the counters)
         jb = order_ref[i, j]
 
         @pl.when(j == 0)
@@ -81,21 +89,34 @@ def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
         # raw formula yields NaN (qp=0) or +inf here.  Both are safe —
         # NaN >= tau is False so the tile skips; +inf computes the tile and
         # vmask masks every row.  No explicit branch needed in-kernel.
-        ub = per_p.min(axis=-1)                           # [BM]
+        ub = per_p.min(axis=1, keepdims=True)             # [BM, 1]
         if use_cap:
             # extra pivot-similarity operand: the precomputed joint
-            # multi-pivot cap for this (query row, visited tile) — min of
-            # valid upper bounds is a valid upper bound (DESIGN.md §3.8)
-            ub = jnp.minimum(ub, cap_ref[...][:, 0])
+            # multi-pivot cap for this (query row, visited tile), fetched as
+            # the 128-tile slab holding column jb — min of valid upper
+            # bounds is a valid upper bound (DESIGN.md §3.8)
+            slab = cap_ref[...]                           # [BM, 128]
+            lane = jax.lax.broadcasted_iota(jnp.int32, slab.shape, 1)
+            cap = jnp.max(jnp.where(lane == jb % _LANES, slab, _NEG_INF),
+                          axis=1, keepdims=True)
+            ub = jnp.minimum(ub, cap)
 
-        tau = top_s[:, k - 1]                             # running kth best
-        row = i * bm + jax.lax.broadcasted_iota(jnp.int32, (qp.shape[0], 1), 0)[:, 0]
-        live = row < nvalid_ref[0, 1]                     # padded query rows
+        lane_k = jax.lax.broadcasted_iota(jnp.int32, (bm, kp), 1)
+        in_k = lane_k < k
+
+        def kth_best(top):
+            # the running k-th best is the MIN of the k (unsorted) slots
+            return jnp.min(jnp.where(in_k, top, jnp.inf), axis=1,
+                           keepdims=True)                 # [BM, 1]
+
+        tau = kth_best(top_s[...])
+        row = i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+        live = row < mvalid_ref[0]                        # padded query rows
         # per-row db validity for this tile: padding AND tombstoned rows.
         # Mutable indexes (repro.core.online) tombstone-delete in place, so
         # valid rows need not be a prefix — a scalar n_valid cut-off would
         # score deleted rows into the top-k.
-        vmask = rv_ref[...][:, 0] > 0                     # [BN]
+        vmask = rv_ref[...] > 0                           # [1, BN]
         if prune:
             # padded query rows (>= m_valid) must not force computation
             needed = jnp.any((ub + margin >= tau) & live)
@@ -109,50 +130,54 @@ def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
             # whether the tile matmul itself was skipped (the statistic
             # measures bound power, not work done); unrolled over the P
             # pivots to keep intermediates at [BM, BN].
-            dpv = dp_ref[...].astype(jnp.float32)         # [BN, P]
+            dpv = dp_ref[...].astype(jnp.float32)         # [P, BN]
             eub = None
-            for p_i in range(dpv.shape[1]):
+            for p_i in range(dpv.shape[0]):
                 a = qp[:, p_i:p_i + 1]                    # [BM, 1]
-                b = dpv[:, p_i][None, :]                  # [1, BN]
+                b = dpv[p_i:p_i + 1, :]                   # [1, BN]
                 rad = rad_q[:, p_i:p_i + 1] * jnp.maximum(0.0, 1.0 - b * b)
                 cand = a * b + jnp.sqrt(rad)
                 eub = cand if eub is None else jnp.minimum(eub, cand)
-            epruned = ((eub + margin < tau[:, None])
-                       & vmask[None, :] & live[:, None])
-            elem_ref[0, 0] = epruned.sum().astype(jnp.int32)
+            epruned = (eub + margin < tau) & vmask & live
+            elem_ref[i, jb] = jnp.sum(epruned.astype(jnp.int32))
 
         @pl.when(needed)
         def _compute():
-            qn = qn_ref[...]
-            db = db_ref[...]
             scores = jax.lax.dot_general(
-                qn, db, (((1,), (1,)), ((), ())),
+                qn_ref[...], db_ref[...], (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32,
             )                                             # [BM, BN]
-            col = jb * bn + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            scores = jnp.where(vmask[None, :], scores, _NEG_INF)  # pad/tombstone
-            cand_s = jnp.concatenate([top_s[...], scores], axis=1)
-            cand_i = jnp.concatenate([top_i[...], col], axis=1)
-            width = cand_s.shape[1]
-            lanes = jax.lax.broadcasted_iota(jnp.int32, (cand_s.shape[0], width), 1)
-            new_s = []
-            new_i = []
-            for _ in range(k):                            # unrolled extraction
-                m = jnp.max(cand_s, axis=1)
-                am = jnp.argmax(cand_s, axis=1).astype(jnp.int32)
-                onehot = lanes == am[:, None]
-                new_s.append(m)
-                new_i.append(jnp.sum(jnp.where(onehot, cand_i, 0), axis=1))
-                cand_s = jnp.where(onehot, _NEG_INF, cand_s)
-            top_s[...] = jnp.stack(new_s, axis=1)
-            top_i[...] = jnp.stack(new_i, axis=1)
+            sc_ref[...] = jnp.where(vmask, scores, _NEG_INF)  # pad/tombstone
+            lane_n = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
 
-        computed_ref[0, 0] = needed.astype(jnp.int32) if prune else jnp.int32(1)
+            def best_left(s, top):
+                # rows whose best remaining tile score beats their k-th best
+                return (jnp.max(s, axis=1, keepdims=True) > kth_best(top)) & live
 
-        @pl.when(j == nj - 1)
-        def _flush():
-            top_s_out[...] = top_s[...]
-            top_i_out[...] = top_i[...]
+            def merge_round(_):
+                s, top, ids = sc_ref[...], top_s[...], top_i[...]
+                m = jnp.max(s, axis=1, keepdims=True)
+                kth = kth_best(top)
+                take = (m > kth) & live
+                # first lane holding the row max / the row's k-th best slot
+                am = jnp.min(jnp.where(s == m, lane_n, bn), axis=1,
+                             keepdims=True)
+                slot = jnp.min(jnp.where(in_k & (top == kth), lane_k, kp),
+                               axis=1, keepdims=True)
+                put = take & (lane_k == slot)
+                top = jnp.where(put, m, top)
+                top_s[...] = top
+                top_i[...] = jnp.where(put, jb * bn + am, ids)
+                s = jnp.where(take & (lane_n == am), _NEG_INF, s)
+                sc_ref[...] = s
+                return jnp.any(best_left(s, top))
+
+            jax.lax.while_loop(lambda go: go, merge_round,
+                               jnp.any(best_left(sc_ref[...], top_s[...])))
+
+        computed_ref[i, jb] = (jnp.asarray(needed).astype(jnp.int32)
+                               if prune else jnp.int32(1))
 
     return kernel
 
@@ -220,7 +245,7 @@ def pruned_topk(
                whose individual Eq. 13 bound is below the running τ — the
                backend-uniform ``elem_prune_frac`` numerator.
 
-    Returns (sims [M, k] f32, idx [M, k] i32 positions into db,
+    Returns (sims [M, k] f32 descending, idx [M, k] i32 positions into db,
     computed [M_tiles, N_tiles] i32 — which db tiles did real work, indexed
     by TILE id, not visit step — and elem_pruned [M_tiles, N_tiles] i32
     per-tile pruned-element counts, ``None`` unless ``element_stats``).
@@ -234,24 +259,23 @@ def pruned_topk(
         raise ValueError("element_stats=True requires dp ([N, P] per-row "
                          "pivot similarities)")
     mp = -(-m // bm) * bm
+    nt = n // bn
+    kp = -(-k // _LANES) * _LANES                  # lane-dense top-k slots
     qn_p = jnp.pad(qn, ((0, mp - m), (0, 0)))
     # padded query rows are masked out of the prune predicate via m_valid
     qp_p = jnp.pad(qp, ((0, mp - m), (0, 0)), constant_values=1.0)
     if m_valid is None:
         m_valid = m
-    nv = jnp.stack([
-        jnp.asarray(n_valid, jnp.int32).reshape(()),
-        jnp.asarray(m_valid, jnp.int32).reshape(()),
-    ]).reshape(1, 2)
+    mv = jnp.asarray(m_valid, jnp.int32).reshape(1)
     if row_valid is None:
         row_valid = jnp.arange(n) < jnp.asarray(n_valid, jnp.int32)
-    rv = row_valid.astype(jnp.int32).reshape(n, 1)
+    rv = row_valid.astype(jnp.int32).reshape(nt, 1, bn)
     if tau_init is None:
         tau = jnp.full((mp, 1), _NEG_INF, jnp.float32)
     else:
         tau = jnp.pad(tau_init.reshape(m, 1).astype(jnp.float32) - 1e-6,
                       ((0, mp - m), (0, 0)), constant_values=_NEG_INF)
-    grid = (mp // bm, n // bn)
+    grid = (mp // bm, nt)
     if block_order is None:
         block_order = jnp.broadcast_to(
             jnp.arange(grid[1], dtype=jnp.int32)[None, :], grid)
@@ -260,52 +284,57 @@ def pruned_topk(
     use_cap = ub_cap is not None
     kern = _make_kernel(k, bm, bn, margin, prune, element_stats,
                         use_cap=use_cap)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out_shape = [
-        jax.ShapeDtypeStruct((mp, k), jnp.float32),
-        jax.ShapeDtypeStruct((mp, k), jnp.int32),
+        jax.ShapeDtypeStruct((mp, kp), jnp.float32),
+        jax.ShapeDtypeStruct((mp, kp), jnp.int32),
         jax.ShapeDtypeStruct(grid, jnp.int32),
     ]
     in_specs = [
-        pl.BlockSpec((1, 2), lambda i, j, ord_: (0, 0)),  # n_valid, m_valid
-        pl.BlockSpec((bm, 1), lambda i, j, ord_: (i, 0)),  # tau seeds
-        pl.BlockSpec((bm, d), lambda i, j, ord_: (i, 0)),  # qn
-        pl.BlockSpec((bn, d), lambda i, j, ord_: (ord_[i, j], 0)),  # db
-        pl.BlockSpec((bm, p), lambda i, j, ord_: (i, 0)),  # qp
-        pl.BlockSpec((1, p), lambda i, j, ord_: (ord_[i, j], 0)),   # lo
-        pl.BlockSpec((1, p), lambda i, j, ord_: (ord_[i, j], 0)),   # hi
-        pl.BlockSpec((bn, 1), lambda i, j, ord_: (ord_[i, j], 0)),  # row valid
+        pl.BlockSpec((bm, 1), lambda i, j, ord_, mv_: (i, 0)),  # tau seeds
+        pl.BlockSpec((bm, d), lambda i, j, ord_, mv_: (i, 0)),  # qn
+        pl.BlockSpec((bn, d), lambda i, j, ord_, mv_: (ord_[i, j], 0)),  # db
+        pl.BlockSpec((bm, p), lambda i, j, ord_, mv_: (i, 0)),  # qp
+        pl.BlockSpec((None, 1, p),
+                     lambda i, j, ord_, mv_: (ord_[i, j], 0, 0)),  # lo
+        pl.BlockSpec((None, 1, p),
+                     lambda i, j, ord_, mv_: (ord_[i, j], 0, 0)),  # hi
+        pl.BlockSpec((None, 1, bn),
+                     lambda i, j, ord_, mv_: (ord_[i, j], 0, 0)),  # row valid
     ]
+    # computed is indexed by the VISITED tile id, not the step
     out_specs = [
-        pl.BlockSpec((bm, k), lambda i, j, ord_: (i, 0)),
-        pl.BlockSpec((bm, k), lambda i, j, ord_: (i, 0)),
-        # computed is indexed by the VISITED tile id, not the step
-        pl.BlockSpec((1, 1), lambda i, j, ord_: (i, ord_[i, j])),
+        pl.BlockSpec((bm, kp), lambda i, j, ord_, mv_: (i, 0)),
+        pl.BlockSpec((bm, kp), lambda i, j, ord_, mv_: (i, 0)),
+        smem,
     ]
-    operands = [block_order, nv, tau, qn_p, db, qp_p, dp_min, dp_max, rv]
+    operands = [block_order, mv, tau, qn_p, db, qp_p,
+                dp_min.reshape(nt, 1, p), dp_max.reshape(nt, 1, p), rv]
     if use_cap:
-        assert ub_cap.shape == (m, grid[1]), (ub_cap.shape, m, grid)
+        assert ub_cap.shape == (m, nt), (ub_cap.shape, m, nt)
         # padded query rows carry cap 0: their ub shrinks, but the prune
-        # predicate already masks them out via m_valid / `live`
-        cap_p = jnp.pad(ub_cap.astype(jnp.float32), ((0, mp - m), (0, 0)))
-        in_specs.append(
-            pl.BlockSpec((bm, 1), lambda i, j, ord_: (i, ord_[i, j])))
+        # predicate already masks them out via m_valid / `live`.  Tile
+        # columns pad to whole 128-lane slabs.
+        ntc = -(-nt // _LANES) * _LANES
+        cap_p = jnp.pad(ub_cap.astype(jnp.float32),
+                        ((0, mp - m), (0, ntc - nt)))
+        in_specs.append(pl.BlockSpec(
+            (bm, _LANES), lambda i, j, ord_, mv_: (i, ord_[i, j] // _LANES)))
         operands.append(cap_p)
     if element_stats:
-        in_specs.append(
-            pl.BlockSpec((bn, p), lambda i, j, ord_: (ord_[i, j], 0)))  # dp
-        operands.append(dp)
+        # [nt, P, BN]: each visited tile's pivot similarities, lane-dense
+        dp_t = dp.reshape(nt, bn, p).transpose(0, 2, 1)
+        in_specs.append(pl.BlockSpec(
+            (None, p, bn), lambda i, j, ord_, mv_: (ord_[i, j], 0, 0)))
+        operands.append(dp_t)
         out_shape.append(jax.ShapeDtypeStruct(grid, jnp.int32))
-        out_specs.append(
-            pl.BlockSpec((1, 1), lambda i, j, ord_: (i, ord_[i, j])))
+        out_specs.append(smem)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                                # block_order
+        num_scalar_prefetch=2,                        # block_order, m_valid
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((bm, k), jnp.float32),
-            pltpu.VMEM((bm, k), jnp.int32),
-        ],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],  # merge scores
     )
     out = pl.pallas_call(
         kern,
@@ -315,4 +344,7 @@ def pruned_topk(
     )(*operands)
     top_s, top_i, computed = out[:3]
     elem = out[3] if element_stats else None
-    return top_s[:m], top_i[:m], computed, elem
+    # the k slots are unsorted in-kernel; order them once here
+    sims, sel = jax.lax.top_k(top_s[:m, :k], k)
+    idx = jnp.take_along_axis(top_i[:m, :k], sel, axis=1)
+    return sims, idx, computed, elem

@@ -46,6 +46,10 @@ __all__ = [
 
 _REGISTRY: dict[str, object] = {}
 
+#: every score and pivot-similarity matmul runs at f32 precision (an f32
+#: dot on the TPU MXU otherwise takes one bf16 pass; DESIGN.md §3.12)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def register_backend(name: str):
     """Class decorator: register a backend under ``name`` (instantiated)."""
@@ -76,7 +80,7 @@ def available_backends() -> list[str]:
 def prep_queries(index: BlockIndex, queries: Array):
     """Normalize queries and compute query-pivot similarities once."""
     qn = normalize(jnp.asarray(queries, jnp.float32))
-    return qn, qn @ index.pivots.T
+    return qn, jnp.dot(qn, index.pivots.T, precision=_HIGHEST)
 
 
 @jax.jit
@@ -136,7 +140,7 @@ def tau_warm_start(qn: Array, db_blocks: Array, valid_blocks: Array,
     best = jax.lax.top_k(ub, n_pre)[1]                  # [m, n_pre]
     blk = db_blocks[best].reshape(m, n_pre * bs, d)
     vb = valid_blocks[best].reshape(m, n_pre * bs)
-    scores = jnp.einsum("md,mcd->mc", qn, blk)
+    scores = jnp.einsum("md,mcd->mc", qn, blk, precision=_HIGHEST)
     scores = jnp.where(vb, scores, -jnp.inf)
     # kth_value, not top_k(...)[0][:, -1]: the naive slice breaks XLA's
     # TopkRewriter and this line becomes a full sort (~10x, see kref)
@@ -291,7 +295,7 @@ def scan_search(
             needed = jnp.ones((m,), bool)
         if has_mask:
             needed = needed & lmask
-        scores = qn @ blk.T                                   # [m, bs]
+        scores = jnp.dot(qn, blk.T, precision=_HIGHEST)       # [m, bs]
         scores = jnp.where(vb[None, :], scores, -jnp.inf)
         scores = jnp.where(needed[:, None], scores, -jnp.inf)
         cand_s = jnp.concatenate([top_s, scores], axis=1)
@@ -436,7 +440,7 @@ def brute_search(index: BlockIndex, qn: Array, k: int):
     matters here more than anywhere: ``auto_backend`` routes exactly the
     tiny datastores where ``k > n`` is most likely to brute.
     """
-    scores = qn @ index.db.T
+    scores = jnp.dot(qn, index.db.T, precision=_HIGHEST)
     scores = jnp.where(index.valid[None, :], scores, -jnp.inf)
     kk = min(k, scores.shape[-1])
     sims, pos = jax.lax.top_k(scores, kk)

@@ -160,6 +160,11 @@ class SearchEngine:
         sort_queries: bool = True,
         interpret: bool | None = None,
     ):
+        if mesh is not None:
+            # one mesh type on every sharded path, whatever the caller made
+            from repro.core.distributed import auto_mesh, on_mesh
+            mesh = auto_mesh(mesh)
+            index = on_mesh(index, mesh)
         self.index = index
         self.mesh = mesh
         self.axis_names = axis_names
@@ -261,7 +266,6 @@ class SearchEngine:
         pivot_method: str = "maxmin",
         reorder: bool = True,
         seed: int = 0,
-        n_shards: int | None = None,
         mesh=None,
         distributed: bool = False,
         global_rows: int | None = None,
@@ -270,9 +274,10 @@ class SearchEngine:
     ) -> "SearchEngine":
         """Build the index and wrap it in an engine in one call.
 
-        Pass ``mesh`` (and optionally ``n_shards``, default one shard per
-        mesh device) to build a sharded datastore served by the
-        ``sharded`` backend.
+        Pass ``mesh`` to build a sharded datastore served by the
+        ``sharded`` backend: one shard per mesh device, each built on the
+        device that holds it.  ``db`` may be a host array or a device
+        array already split by rows over the mesh.
 
         ``n_pivots`` here is the *index* pivot count (interval tables and
         joint-bound table width); ``bound_pivots`` is the engine's search
@@ -290,35 +295,34 @@ class SearchEngine:
         """
         if bound_pivots is not None:
             engine_kw["n_pivots"] = bound_pivots
-        if distributed:
-            if mesh is None:
-                raise ValueError(
-                    "SearchEngine.build(distributed=True) needs mesh= (the "
-                    "global mesh the datastore shards across)")
-            from repro.core.distributed import build_sharded_index_local
+        if distributed and mesh is None:
+            raise ValueError(
+                "SearchEngine.build(distributed=True) needs mesh= (the "
+                "global mesh the datastore shards across)")
+        if mesh is not None:
+            from repro.core.distributed import (build_sharded_index_local,
+                                                local_shard_rows)
+            axis_names = engine_kw.get("axis_names")
+            if not hasattr(db, "shape"):
+                db = np.asarray(db, np.float32)
             if global_rows is None:
-                if jax.process_count() > 1:
+                if distributed and jax.process_count() > 1:
                     raise ValueError(
                         "SearchEngine.build(distributed=True) on a "
                         "multi-process mesh needs global_rows= (the total "
                         "datastore rows across all hosts; db holds only "
                         "this host's slice, so the split cannot be "
                         "inferred from it)")
-                global_rows = int(np.asarray(db).shape[0])
+                global_rows = int(db.shape[0])
+            if not distributed and jax.process_count() > 1:
+                # every host passed the whole datastore: keep its own rows
+                _, owned = local_shard_rows(global_rows, mesh, axis_names)
+                db = np.concatenate([np.asarray(db[a:b], np.float32)
+                                     for _, a, b in owned])
             idx = build_sharded_index_local(
-                np.asarray(db), mesh, global_rows=global_rows,
-                axis_names=engine_kw.get("axis_names"), n_pivots=n_pivots,
-                block_size=block_size, pivot_method=pivot_method)
-            return cls(idx, mesh=mesh, **engine_kw)
-        if mesh is not None:
-            from repro.core.distributed import (build_sharded_index,
-                                                place_sharded_index)
-            n_shards = n_shards or mesh.devices.size
-            idx = build_sharded_index(
-                np.asarray(db), n_shards, n_pivots=n_pivots,
-                block_size=block_size, pivot_method=pivot_method)
-            idx = place_sharded_index(idx, mesh,
-                                      engine_kw.get("axis_names"))
+                db, mesh, global_rows=global_rows, axis_names=axis_names,
+                n_pivots=n_pivots, block_size=block_size,
+                pivot_method=pivot_method)
             return cls(idx, mesh=mesh, **engine_kw)
         idx = build_index(db, n_pivots=n_pivots, block_size=block_size,
                           pivot_method=pivot_method, reorder=reorder,

@@ -319,7 +319,8 @@ def tree_warm_start_topk(tree: TreeIndex, qn: Array, qp: Array, k: int,
     valid_blocks = idx.valid.reshape(nb, bs)
     blk = db_blocks[blocks].reshape(m, w * bs, -1)
     vb = (valid_blocks[blocks] & okb[:, :, None]).reshape(m, w * bs)
-    scores = jnp.einsum("md,mcd->mc", qn, blk)
+    scores = jnp.einsum("md,mcd->mc", qn, blk,
+                        precision=jax.lax.Precision.HIGHEST)
     scores = jnp.where(vb, scores, -jnp.inf)
     kk = min(k, w * bs)
     # barrier: single-device callers immediately slice the k-th column

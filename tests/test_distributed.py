@@ -162,3 +162,49 @@ def test_dryrun_single_cell_small():
         print("bytes/dev",
               rec["memory"]["argument_bytes"] + rec["memory"]["temp_bytes"])
     """, devices=512)
+
+
+def test_auto_mesh_normalizes_explicit_axes():
+    """jax.make_mesh makes Explicit axes; the sharded paths take any mesh
+    and run on its Auto twin (same devices, same names)."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.core.distributed import auto_mesh
+    mesh = jax.make_mesh((1,), ("data",))
+    auto = auto_mesh(mesh)
+    assert auto.axis_types == (AxisType.Auto,)
+    assert auto.axis_names == mesh.axis_names
+    assert list(auto.devices.flat) == list(mesh.devices.flat)
+    assert auto_mesh(auto) is auto
+
+
+def test_sharded_build_puts_each_shard_on_its_own_device():
+    """SearchEngine.build(mesh=...) builds every shard on the device that
+    holds it — from host rows or from a device array already split by
+    rows — and both inputs give the same index."""
+    _run("""
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core import ref
+        from repro.core.distributed import auto_mesh
+        from repro.search import SearchEngine
+        rng = np.random.default_rng(5)
+        db = rng.normal(size=(4 * 300, 16)).astype(np.float32)
+        mesh = jax.make_mesh((4,), ("data",))
+        a = SearchEngine.build(db, n_pivots=4, block_size=32, mesh=mesh)
+        rows = jax.device_put(db, NamedSharding(auto_mesh(mesh), P("data")))
+        b = SearchEngine.build(rows, n_pivots=4, block_size=32, mesh=mesh)
+        for leaf_a, leaf_b in zip(jax.tree.leaves(a.index),
+                                  jax.tree.leaves(b.index)):
+            shards = leaf_a.addressable_shards
+            assert len({s.device for s in shards}) == 4
+            assert all(s.data.shape[0] == 1 for s in shards)
+            np.testing.assert_array_equal(np.asarray(leaf_a),
+                                          np.asarray(leaf_b))
+        q = rng.normal(size=(5, 16)).astype(np.float32)
+        s, i, _ = a.search(jnp.asarray(q), 6)
+        sref, _ = ref.brute_force_knn(q, db, 6)
+        np.testing.assert_allclose(np.asarray(s), sref, atol=2e-5)
+        print("ok")
+    """, devices=4)

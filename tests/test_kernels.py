@@ -136,3 +136,22 @@ def test_raw_kernel_element_counter(rng):
     assert (elem >= 0).all() and (elem <= 8 * bn).all()
     # clustered near-duplicate queries: τ rises fast, some elements prune
     assert elem.sum() > 0
+
+
+def test_raw_kernel_k_beyond_one_lane_slab(rng):
+    """k > 128 spans two 128-lane slabs of the in-kernel top-k block."""
+    db = clustered(rng, 512, 16, n_centers=4, noise=0.05)
+    q = cref.normalize(db[:8] + 0.01 * rng.normal(size=(8, 16))).astype(
+        np.float32)
+    piv = db[:4]
+    qp = (q @ piv.T).astype(np.float32)
+    dp = (db @ piv.T).astype(np.float32)
+    bn = 256
+    lo = dp.reshape(-1, bn, 4).min(1)
+    hi = dp.reshape(-1, bn, 4).max(1)
+    s, i, _, _ = pruned_topk(
+        jnp.asarray(q), jnp.asarray(db), jnp.asarray(qp), jnp.asarray(lo),
+        jnp.asarray(hi), 512, k=150, bm=8, bn=bn, interpret=True)
+    sref, iref = cref.brute_force_knn(q, db, 150)
+    np.testing.assert_allclose(np.asarray(s), sref, atol=3e-5)
+    assert (np.sort(np.asarray(i), 1) == np.sort(iref, 1)).mean() > 0.98

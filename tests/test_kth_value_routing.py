@@ -10,7 +10,7 @@ barrier inline at their tuple-unpack sites because they need the whole
 
 repro-lint R001 catches the *syntactic* pattern; these tests pin the
 *semantic* property — each warm-start path's jaxpr still contains the
-``opt_barrier`` that keeps the rewrite alive, and the flat prescan
+``optimization_barrier`` that keeps the rewrite alive, and the flat prescan
 still routes through ``kth_value`` itself — so a refactor cannot drop
 the guard while keeping the naive slice out of R001's sight.
 """
@@ -31,7 +31,7 @@ K = 8
 
 
 def _jaxpr_has_barrier(fn, *args) -> bool:
-    return "opt_barrier" in str(jax.make_jaxpr(fn)(*args))
+    return "optimization_barrier" in str(jax.make_jaxpr(fn)(*args))
 
 
 def _small_tree(seed=0, n=256, d=8):

@@ -1,0 +1,125 @@
+"""Every Pallas kernel of the search path compiles for a TPU v5e chip.
+
+The interpreter that runs the kernels in the CPU tests does not enforce
+Mosaic's rules (the (8, 128) block tiling, VMEM capacity, SMEM scalars);
+only the TPU compiler does.  These tests compile the kernels at
+deployment widths (d=768, one kernel tile of 256 rows, k=10 and k=100)
+against a *described* v5e topology — no chip is needed — and check that
+each compiled program holds the kernel as a ``tpu_custom_call``.
+
+The topology is described inside a fixture (never at import), so every
+pytest-xdist worker collects the same tests and only the worker that runs
+this file loads the TPU compiler.  The persistent compilation cache is off
+around the compiles: an entry written for a described chip cannot be read
+back here.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.index import BlockIndex
+from repro.kernels import bound_prune, cosine_topk, leaf_gather
+
+D, BN, P = 768, 256, 16       # MS MARCO passage embeddings, kernel tile, pivots
+M = 256                       # queries per search batch
+NT = 256                      # db tiles per compile (65,536 rows)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def shape(one_chip):
+    def make(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    return make
+
+
+def _compiled_text(fn, *args, **kw):
+    return jax.jit(fn, **kw).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("k,variant", [(10, "engine"), (100, "engine"),
+                                       (10, "joint_cap"),
+                                       (10, "element_stats")])
+def test_pruned_topk_compiles_for_v5e(k, variant, shape, no_persistent_cache):
+    """The fused kernel with τ seeds, a best-first block order and per-row
+    validity — the operands the kernel backend passes — plus the joint
+    cap and element-counter variants."""
+    n = NT * BN
+    extra = {}
+    args = [shape(M, D), shape(n, D), shape(M, P), shape(NT, P),
+            shape(NT, P), shape(M), shape(M // cosine_topk.DEFAULT_BM, NT,
+                                          dtype=jnp.int32),
+            shape(n, dtype=jnp.bool_)]
+    if variant == "joint_cap":
+        args.append(shape(M, NT))
+        extra["cap"] = True
+    if variant == "element_stats":
+        args.append(shape(n, P))
+        extra["dp"] = True
+
+    def search(qn, db, qp, lo, hi, tau, order, valid, *rest):
+        rest = list(rest)
+        return cosine_topk.pruned_topk(
+            qn, db, qp, lo, hi, valid.sum(), tau_init=tau, block_order=order,
+            row_valid=valid, ub_cap=rest.pop(0) if "cap" in extra else None,
+            dp=rest.pop(0) if "dp" in extra else None, k=k, bn=BN,
+            element_stats="dp" in extra, interpret=False)
+
+    assert "tpu_custom_call" in _compiled_text(search, *args)
+
+
+@pytest.mark.parametrize("with_cap", [False, True])
+def test_block_bounds_compiles_for_v5e(with_cap, shape, no_persistent_cache):
+    nb = 8635                          # 2.2M rows in 256-row tiles
+    args = [shape(M, P), shape(nb, P), shape(nb, P)]
+    if with_cap:
+        args.append(shape(M, nb))
+    text = _compiled_text(
+        lambda *a: bound_prune.block_bounds(*a, interpret=False), *args)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("k", [10, 100])
+def test_gathered_topk_compiles_for_v5e(k, shape, no_persistent_cache):
+    """The tree backend's kernel leaf stage: surviving 256-row blocks
+    gathered and searched by the same kernel."""
+    n_pad, n_keep = NT * BN, 64
+    nb = NT
+    index = BlockIndex(shape(n_pad, D), shape(n_pad, P), shape(P, D),
+                       shape(nb, P), shape(nb, P),
+                       shape(n_pad, dtype=jnp.bool_),
+                       shape(n_pad, dtype=jnp.int32))
+
+    def leaves(index, keep, qn, qp, tau):
+        return leaf_gather.gathered_topk(index, keep, qn, qp, tau,
+                                         n_keep=n_keep, k=k, interpret=False)
+
+    text = _compiled_text(leaves, index, shape(n_keep, dtype=jnp.int32),
+                          shape(M, D), shape(M, P), shape(M))
+    assert "tpu_custom_call" in text

@@ -83,21 +83,21 @@ class TopkSliceRule(Rule):
 
 
 # --------------------------------------------------------------------------
-# R002 — post-0.4.37 jax APIs must stay behind repro.dist.compat
+# R002 — version-sensitive jax APIs must stay behind repro.dist.compat
 # --------------------------------------------------------------------------
 
 @register
 class CompatOnlyApiRule(Rule):
     """Version-sensitive jax APIs are reachable only through dist/compat.py.
 
-    Provenance: ROADMAP "Seed-era note" and dist/compat.py.  The container
-    ships jax 0.4.37: ``jax.shard_map`` (and its ``check_vma`` signature)
-    does not exist, ``optimization_barrier`` has no grad rule, and
-    ``make_array_from_process_local_data``'s signature is in flux.  Every
-    call site goes through :mod:`repro.dist.compat` so a jax bump (or
-    downgrade) is a one-file fix; a direct use works on the author's jax
-    and breaks on the next — PR 1 restored a whole package that died this
-    way.
+    Provenance: ROADMAP "Seed-era note" and dist/compat.py.  These APIs
+    moved or changed signature across jax releases (``jax.shard_map`` and
+    its ``check_vma`` spelling, the barrier's grad rule,
+    ``make_array_from_process_local_data``).  The installed jax is pinned
+    in pyproject.toml; every call site goes through
+    :mod:`repro.dist.compat` so the next bump is a one-file fix — a direct
+    use works on the author's jax and breaks on the next, and PR 1
+    restored a whole package that died this way.
     """
 
     id = "R002"
@@ -120,8 +120,8 @@ class CompatOnlyApiRule(Rule):
                         for b in self._BANNED):
             ctx.report(self, node,
                        f"{name} is version-shimmed — import it from "
-                       f"repro.dist.compat (jax 0.4.37 contract, ROADMAP "
-                       f"seed-era note)")
+                       f"repro.dist.compat (the one jax-version funnel, "
+                       f"ROADMAP seed-era note)")
 
     def visit_Attribute(self, node: ast.Attribute, ctx: FileContext) -> None:
         parent = ctx.parents.get(node)
