@@ -115,5 +115,6 @@ def block_bounds(
         out_specs=pl.BlockSpec((bm_, bb_), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, nbp), jnp.float32),
         interpret=interpret,
+        name="block_bounds",
     )(*operands)
     return out[:m, :nb]

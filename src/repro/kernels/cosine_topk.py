@@ -17,7 +17,9 @@ the kernel.  The merge keeps the k slots *unsorted*: each round moves
 every row's best remaining tile score into that row's current minimum
 slot, and the rounds stop as soon as no row's best remaining score beats
 its k-th best — after a warm τ most computed tiles need zero or one
-round, and no round allocates anything wider than ``[BM, BN]``.
+round, and no round allocates anything wider than ``[BM, BN]``.  The
+rounds are counted, per query tile, into an SMEM output
+(``SearchStats.merge_rounds``).
 
 Layout (what Mosaic's (8, 128) tiling rule forced when the kernel was
 first compiled for TPU v5e): per-tile pivot intervals and row validity
@@ -56,8 +58,8 @@ def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
         rest = list(rest)
         cap_ref = rest.pop(0) if use_cap else None
         dp_ref = rest.pop(0) if element_stats else None
-        top_s, top_i, computed_ref = rest[:3]
-        elem_ref = rest[3] if element_stats else None
+        top_s, top_i, computed_ref, rounds_ref = rest[:4]
+        elem_ref = rest[4] if element_stats else None
         sc_ref = rest[-1]
         i = pl.program_id(0)
         j = pl.program_id(1)
@@ -77,6 +79,7 @@ def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
             # exactness is preserved because >= k real candidates reach tau.
             top_s[...] = jnp.broadcast_to(tau_ref[...], top_s.shape)
             top_i[...] = jnp.full(top_i.shape, -1, jnp.int32)
+            rounds_ref[i] = jnp.int32(0)
 
         qp = qp_ref[...].astype(jnp.float32)              # [BM, P]
         lo = lo_ref[...].astype(jnp.float32)              # [1, P]
@@ -155,7 +158,8 @@ def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
                 # rows whose best remaining tile score beats their k-th best
                 return (jnp.max(s, axis=1, keepdims=True) > kth_best(top)) & live
 
-            def merge_round(_):
+            def merge_round(carry):
+                _, n = carry
                 s, top, ids = sc_ref[...], top_s[...], top_i[...]
                 m = jnp.max(s, axis=1, keepdims=True)
                 kth = kth_best(top)
@@ -171,10 +175,12 @@ def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
                 top_i[...] = jnp.where(put, jb * bn + am, ids)
                 s = jnp.where(take & (lane_n == am), _NEG_INF, s)
                 sc_ref[...] = s
-                return jnp.any(best_left(s, top))
+                return jnp.any(best_left(s, top)), n + 1
 
-            jax.lax.while_loop(lambda go: go, merge_round,
-                               jnp.any(best_left(sc_ref[...], top_s[...])))
+            _, n = jax.lax.while_loop(
+                lambda carry: carry[0], merge_round,
+                (jnp.any(best_left(sc_ref[...], top_s[...])), jnp.int32(0)))
+            rounds_ref[i] = rounds_ref[i] + n
 
         computed_ref[i, jb] = (jnp.asarray(needed).astype(jnp.int32)
                                if prune else jnp.int32(1))
@@ -247,8 +253,10 @@ def pruned_topk(
 
     Returns (sims [M, k] f32 descending, idx [M, k] i32 positions into db,
     computed [M_tiles, N_tiles] i32 — which db tiles did real work, indexed
-    by TILE id, not visit step — and elem_pruned [M_tiles, N_tiles] i32
-    per-tile pruned-element counts, ``None`` unless ``element_stats``).
+    by TILE id, not visit step — elem_pruned [M_tiles, N_tiles] i32
+    per-tile pruned-element counts, ``None`` unless ``element_stats``, and
+    merge_rounds [M_tiles] i32, the top-k merge rounds each query tile ran
+    summed over its computed tiles).
     """
     m, d = qn.shape
     n = db.shape[0]
@@ -289,6 +297,7 @@ def pruned_topk(
         jax.ShapeDtypeStruct((mp, kp), jnp.float32),
         jax.ShapeDtypeStruct((mp, kp), jnp.int32),
         jax.ShapeDtypeStruct(grid, jnp.int32),
+        jax.ShapeDtypeStruct(grid[:1], jnp.int32),
     ]
     in_specs = [
         pl.BlockSpec((bm, 1), lambda i, j, ord_, mv_: (i, 0)),  # tau seeds
@@ -302,10 +311,12 @@ def pruned_topk(
         pl.BlockSpec((None, 1, bn),
                      lambda i, j, ord_, mv_: (ord_[i, j], 0, 0)),  # row valid
     ]
-    # computed is indexed by the VISITED tile id, not the step
+    # computed is indexed by the VISITED tile id, not the step; the merge
+    # rounds are summed per query tile
     out_specs = [
         pl.BlockSpec((bm, kp), lambda i, j, ord_, mv_: (i, 0)),
         pl.BlockSpec((bm, kp), lambda i, j, ord_, mv_: (i, 0)),
+        smem,
         smem,
     ]
     operands = [block_order, mv, tau, qn_p, db, qp_p,
@@ -341,10 +352,11 @@ def pruned_topk(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="pruned_topk",
     )(*operands)
-    top_s, top_i, computed = out[:3]
-    elem = out[3] if element_stats else None
+    top_s, top_i, computed, rounds = out[:4]
+    elem = out[4] if element_stats else None
     # the k slots are unsorted in-kernel; order them once here
     sims, sel = jax.lax.top_k(top_s[:m, :k], k)
     idx = jnp.take_along_axis(top_i[:m, :k], sel, axis=1)
-    return sims, idx, computed, elem
+    return sims, idx, computed, elem, rounds

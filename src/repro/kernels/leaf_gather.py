@@ -95,7 +95,7 @@ def gathered_topk(
     if element_stats:
         dp_c = index.dp.reshape(nb, bs, -1)[keep].reshape(n_keep * bs, -1)
 
-    sims, pos, computed, elem = cosine_topk.pruned_topk(
+    sims, pos, computed, elem, _ = cosine_topk.pruned_topk(
         qn, db_c, qp, lo_c, hi_c, n_valid,
         tau_init=tau0, block_order=block_order, dp=dp_c, row_valid=valid_c,
         k=k, bm=bm, bn=bs, margin=margin, prune=True, interpret=interpret,

@@ -384,45 +384,66 @@ def kernel_search(
     lo, hi = coarsen_intervals(index.dp_min, index.dp_max, factor)
     m = qn.shape[0]
     if sort_queries:
-        perm = query_sort_perm(qp)
-        qn, qp = qn[perm], qp[perm]
+        with jax.named_scope("query_sort"):
+            perm = query_sort_perm(qp)
+            qn, qp = qn[perm], qp[perm]
     n_valid = index.valid.sum().astype(jnp.int32)
 
     ub_cap = None
-    if prune and n_pivots > 0:
-        cap = multipivot_block_cap(index, qn, n_pivots=n_pivots)  # [m, nb]
-        ub_cap = cap.reshape(m, lo.shape[0], -1).max(axis=-1)     # [m, nt]
     ub = None
-    if warm_start or best_first:
-        ub = kref.block_bounds(qp, lo, hi)                    # [m, n_tiles]
-        if ub_cap is not None:
-            ub = jnp.minimum(ub, ub_cap)
+    with jax.named_scope("bound"):
+        if prune and n_pivots > 0:
+            cap = multipivot_block_cap(index, qn, n_pivots=n_pivots)
+            ub_cap = cap.reshape(m, lo.shape[0], -1).max(axis=-1)  # [m, nt]
+        if warm_start or best_first:
+            ub = kref.block_bounds(qp, lo, hi)                # [m, n_tiles]
+            if ub_cap is not None:
+                ub = jnp.minimum(ub, ub_cap)
     tau_init = None
     if warm_start:
-        db_tiles = index.db.reshape(-1, bn, index.db.shape[-1])
-        valid_tiles = index.valid.reshape(-1, bn)
-        n_pre = prescan_blocks(k, bn, db_tiles.shape[0], warm_start_blocks)
-        tau_init = tau_warm_start(qn, db_tiles, valid_tiles, ub, k, n_pre)
+        with jax.named_scope("prescan"):
+            db_tiles = index.db.reshape(-1, bn, index.db.shape[-1])
+            valid_tiles = index.valid.reshape(-1, bn)
+            n_pre = prescan_blocks(k, bn, db_tiles.shape[0],
+                                   warm_start_blocks)
+            tau_init = tau_warm_start(qn, db_tiles, valid_tiles, ub, k,
+                                      n_pre)
     block_order = None
     if best_first:
-        mp = -(-m // bm) * bm
-        nt = lo.shape[0]
-        ub_p = jnp.pad(ub, ((0, mp - m), (0, 0)), constant_values=-jnp.inf)
-        tile_ub = ub_p.reshape(mp // bm, bm, nt).max(axis=1)  # [m_tiles, nt]
-        block_order = jnp.argsort(-tile_ub, axis=1).astype(jnp.int32)
+        with jax.named_scope("order"):
+            mp = -(-m // bm) * bm
+            nt = lo.shape[0]
+            ub_p = jnp.pad(ub, ((0, mp - m), (0, 0)),
+                           constant_values=-jnp.inf)
+            tile_ub = ub_p.reshape(mp // bm, bm, nt).max(axis=1)
+            block_order = jnp.argsort(-tile_ub, axis=1).astype(jnp.int32)
 
-    sims, pos, computed, elem = cosine_topk.pruned_topk(
-        qn, index.db, qp, lo, hi, n_valid,
-        tau_init=tau_init, block_order=block_order,
-        dp=index.dp if element_stats else None, ub_cap=ub_cap,
-        row_valid=index.valid,
-        k=k, bm=bm, bn=bn, margin=margin, prune=prune, interpret=interpret,
-        element_stats=element_stats,
-    )
+    with jax.named_scope("pruned_topk"):
+        sims, pos, computed, elem, rounds = cosine_topk.pruned_topk(
+            qn, index.db, qp, lo, hi, n_valid,
+            tau_init=tau_init, block_order=block_order,
+            dp=index.dp if element_stats else None, ub_cap=ub_cap,
+            row_valid=index.valid,
+            k=k, bm=bm, bn=bn, margin=margin, prune=prune,
+            interpret=interpret, element_stats=element_stats,
+        )
     if sort_queries:
-        inv = jnp.argsort(perm)
-        sims, pos = sims[inv], pos[inv]
-    return sims, pos, computed, elem
+        with jax.named_scope("unsort"):
+            inv = jnp.argsort(perm)
+            sims, pos = sims[inv], pos[inv]
+    return sims, pos, computed, elem, rounds
+
+
+def _kernel_raw(computed, elem, rounds, m, n_valid, element_stats):
+    """The kernel backend's raw stats from its counters."""
+    n_computed = computed.sum()
+    frac = computed.mean()
+    raw = {"block_prune_frac": 1.0 - frac, "tile_computed_frac": frac,
+           "merge_rounds": rounds.sum() / jnp.maximum(n_computed, 1)}
+    if element_stats:
+        raw["elem_prune_frac"] = elem.astype(jnp.float32).sum() / (
+            m * n_valid)
+    return raw
 
 
 # ---------------------------------------------------------------------------
@@ -524,7 +545,7 @@ class KernelBackend:
 
     def run(self, eng, queries, k, *, prune=True, element_stats=False):
         qn, qp = prep_queries(eng.index, queries)
-        s, pos, computed, elem = kernel_search(
+        s, pos, computed, elem, rounds = kernel_search(
             eng.index, qn, qp, k, bm=eng.bm, bn=eng.bn, prune=prune,
             sort_queries=eng.sort_queries, warm_start=eng.warm_start,
             best_first=eng.best_first, margin=eng.margin,
@@ -532,12 +553,8 @@ class KernelBackend:
             warm_start_blocks=eng.warm_start_blocks,
             n_pivots=eng.n_pivots)
         ids = map_row_ids(eng.index.row_ids, pos)
-        frac = computed.mean()
-        raw = {"block_prune_frac": 1.0 - frac, "tile_computed_frac": frac}
-        if element_stats:
-            m = qn.shape[0]
-            raw["elem_prune_frac"] = (
-                elem.astype(jnp.float32).sum() / (m * max(1, eng.n_valid)))
+        raw = _kernel_raw(computed, elem, rounds, qn.shape[0],
+                          max(1, eng.n_valid), element_stats)
         return s, ids, raw
 
     def make_fused(self, eng, k, *, prune, element_stats, donate):
@@ -552,22 +569,20 @@ class KernelBackend:
         @jax.jit
         def fused(index, queries):
             note()
-            qn, qp = prep_queries(index, queries)
-            s, pos, computed, elem = kernel_search(
+            with jax.named_scope("prep"):
+                qn, qp = prep_queries(index, queries)
+            s, pos, computed, elem, rounds = kernel_search(
                 index, qn, qp, k, bm=bm, bn=bn, prune=prune,
                 sort_queries=sq, warm_start=warm_start,
                 best_first=best_first, margin=margin, interpret=interpret,
                 element_stats=element_stats, warm_start_blocks=wsb,
                 n_pivots=n_piv)
-            ids = map_row_ids(index.row_ids, pos)
-            frac = computed.mean()
-            raw = {"block_prune_frac": 1.0 - frac,
-                   "tile_computed_frac": frac}
-            if element_stats:
-                m = qn.shape[0]
-                n_valid = jnp.maximum(index.valid.sum(), 1)  # traced: online
-                raw["elem_prune_frac"] = (
-                    elem.astype(jnp.float32).sum() / (m * n_valid))
+            with jax.named_scope("ids"):
+                ids = map_row_ids(index.row_ids, pos)
+            # traced valid count: online mutation changes it without a retrace
+            raw = _kernel_raw(computed, elem, rounds, qn.shape[0],
+                              jnp.maximum(index.valid.sum(), 1),
+                              element_stats)
             return s, ids, raw
 
         return fused

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.index import BlockIndex, build_index
 from repro.search import backends as _bk
 from repro.search import defaults as _defaults
@@ -403,8 +404,9 @@ class SearchEngine:
         """Trace-time side effect: fused callables call this from inside
         their traced bodies, so it fires exactly once per jit trace and
         never on a cached dispatch — the retrace counter behind
-        ``SearchStats.retraces``."""
+        ``SearchStats.retraces`` and the ``engine.traces`` counter."""
         self._traces += 1
+        obs.count("engine.traces")
 
     def _knob_key(self):
         return (self.warm_start, self.warm_start_blocks, self.best_first,
@@ -485,42 +487,55 @@ class SearchEngine:
         if not hasattr(queries, "shape"):
             queries = jnp.asarray(queries)
         kk = min(k, self.n_slots)
-        traces_before = self._traces
-        fused = self._fused_callable(queries, kk, prune, element_stats)
-        if fused is not None:
-            sims, ids, raw = fused(queries)
-            retraces = self._traces - traces_before
-        else:
-            sims, ids, raw = self.backend.run(
-                self, queries, kk, prune=prune, element_stats=element_stats)
-            # the sharded closure carries the trace hook; other legacy
-            # paths (tree kernel-leaf) are multi-dispatch -> unknown
-            retraces = (self._traces - traces_before
-                        if self.backend_name == "sharded" else None)
-        if kk < k:
-            sims, ids = _pad_topk(sims, ids, k=k)
-        stats = SearchStats(
-            backend=self.backend_name,
-            n_queries=int(queries.shape[0]),
-            k=k,
-            n_blocks=self.n_blocks,
-            block_prune_frac=raw.get("block_prune_frac", 0.0),
-            tile_computed_frac=raw.get("tile_computed_frac"),
-            elem_prune_frac=raw.get("elem_prune_frac"),
-            tree_prune_frac=raw.get("tree_prune_frac"),
-            tree_node_eval_frac=raw.get("tree_node_eval_frac"),
-            warm_start=self.warm_start,
-            best_first=self.best_first,
-            n_pivots=(None if self.backend_name == "brute"
-                      else self.n_pivots),
-            retraces=retraces,
-            generation=(self._online.generation
-                        if self._online is not None else None),
-            decay_estimate=(self._online.decay_estimate
+        with obs.span("engine.search", backend=self.backend_name, k=k,
+                      m=int(queries.shape[0])) as call:
+            traces_before = self._traces
+            with obs.span("engine.dispatch"):
+                fused = self._fused_callable(queries, kk, prune,
+                                             element_stats)
+                if fused is not None:
+                    sims, ids, raw = fused(queries)
+                    retraces = self._traces - traces_before
+                else:
+                    sims, ids, raw = self.backend.run(
+                        self, queries, kk, prune=prune,
+                        element_stats=element_stats)
+                    # the sharded closure carries the trace hook; other
+                    # legacy paths (tree kernel-leaf) are multi-dispatch ->
+                    # unknown
+                    retraces = (self._traces - traces_before
+                                if self.backend_name == "sharded" else None)
+            if kk < k:
+                sims, ids = _pad_topk(sims, ids, k=k)
+            stats = SearchStats(
+                backend=self.backend_name,
+                n_queries=int(queries.shape[0]),
+                k=k,
+                n_blocks=self.n_blocks,
+                block_prune_frac=raw.get("block_prune_frac", 0.0),
+                tile_computed_frac=raw.get("tile_computed_frac"),
+                elem_prune_frac=raw.get("elem_prune_frac"),
+                tree_prune_frac=raw.get("tree_prune_frac"),
+                tree_node_eval_frac=raw.get("tree_node_eval_frac"),
+                merge_rounds=raw.get("merge_rounds"),
+                warm_start=self.warm_start,
+                best_first=self.best_first,
+                n_pivots=(None if self.backend_name == "brute"
+                          else self.n_pivots),
+                retraces=retraces,
+                generation=(self._online.generation
                             if self._online is not None else None),
-            extras={k_: v for k_, v in raw.items()
-                    if k_ not in ("block_prune_frac", "tile_computed_frac",
-                                  "elem_prune_frac", "tree_prune_frac",
-                                  "tree_node_eval_frac")},
-        )
+                decay_estimate=(self._online.decay_estimate
+                                if self._online is not None else None),
+                extras={k_: v for k_, v in raw.items()
+                        if k_ not in ("block_prune_frac",
+                                      "tile_computed_frac",
+                                      "elem_prune_frac", "tree_prune_frac",
+                                      "tree_node_eval_frac",
+                                      "merge_rounds")},
+            )
+            # under an outer jit the stats are tracers: keep none
+            call.note(retraced=retraces,
+                      stats=None if isinstance(queries, jax.core.Tracer)
+                      else stats)
         return sims, ids, stats

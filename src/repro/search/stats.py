@@ -53,6 +53,13 @@ class SearchStats:
     evaluate — the flat scan is 1.0 at the leaf level by construction,
     so lower means the hierarchy is paying for itself.
 
+    ``merge_rounds`` (``kernel`` backend only) is the mean number of
+    rounds the Pallas kernel's top-k merge ran per computed (query tile,
+    db tile) pair: each round moves every row's best remaining tile score
+    into its k slots, and rounds stop once no row's best remaining score
+    beats its k-th best, so it counts the merge's serial steps.  Lazy like
+    the fractions; ``None`` on every other backend.
+
     ``retraces`` is the number of jit traces (trace + XLA compile) this
     ``search`` call triggered through the engine's compiled-function
     cache: 0 means the fully-fused hot path was dispatch-cached (the
@@ -93,6 +100,7 @@ class SearchStats:
     elem_prune_frac: float | None = None
     tree_prune_frac: float | None = None
     tree_node_eval_frac: float | None = None
+    merge_rounds: float | None = None
     warm_start: bool = False
     best_first: bool = False
     n_pivots: int | None = None

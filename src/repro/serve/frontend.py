@@ -22,13 +22,25 @@ worker serializes all device work through a single executor thread;
 online inserts/deletes (:meth:`SearchEngine.online`) interleave safely
 *between* microbatches by going through :meth:`run`, the same
 single-thread funnel.
+
+While a profiler session records, each microbatch's steps are
+:mod:`repro.obs` spans carrying its ``batch`` sequence number
+(``frontend.coalesce`` with its ``frontend.hold`` waits,
+``frontend.pad``, ``frontend.device`` around the device thread's
+``engine.search`` and ``frontend.fetch``, ``frontend.hop``,
+``frontend.resolve``), and each query's time in the queue is a
+``frontend.queue_wait`` record; docs/search-api.md "Tracing" lists them.
 """
 from __future__ import annotations
 
 import asyncio
+import contextvars
+import time
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
+
+from repro import obs
 
 __all__ = ["ContinuousBatcher"]
 
@@ -60,6 +72,7 @@ class ContinuousBatcher:
         #: microbatches dispatched / queries served (occupancy telemetry)
         self.n_batches = 0
         self.n_queries = 0
+        self._seq = 0                   # microbatch id on the trace
         self._queue: asyncio.Queue = asyncio.Queue()
         self._pool = ThreadPoolExecutor(max_workers=1)
         self._worker: asyncio.Task | None = None
@@ -129,7 +142,8 @@ class ContinuousBatcher:
             self._loop = loop
             self._worker = loop.create_task(self._run_worker())
         fut = loop.create_future()
-        self._queue.put_nowait((q, fut))
+        self._queue.put_nowait(
+            (q, fut, time.perf_counter_ns() if obs.enabled() else 0))
         return await fut
 
     async def run(self, fn, *args):
@@ -144,37 +158,66 @@ class ContinuousBatcher:
         loop = asyncio.get_running_loop()
         while True:
             first = await self._queue.get()
-            batch = [first]
-            deadline = loop.time() + self.max_wait
-            while len(batch) < self.max_batch:
-                timeout = deadline - loop.time()
-                if timeout <= 0 and self._queue.empty():
-                    break
-                try:
-                    batch.append(await asyncio.wait_for(
-                        self._queue.get(), max(timeout, 0.0)))
-                except asyncio.TimeoutError:
-                    break
+            seq = self._seq
+            self._seq += 1
+            on = obs.enabled()
+            with obs.span("frontend.coalesce", batch=seq):
+                batch = [first]
+                deadline = loop.time() + self.max_wait
+                while len(batch) < self.max_batch:
+                    timeout = deadline - loop.time()
+                    if timeout <= 0 and self._queue.empty():
+                        break
+                    hold = (obs.span("frontend.hold", batch=seq)
+                            if on and self._queue.empty() else obs.OFF)
+                    try:
+                        with hold:
+                            batch.append(await asyncio.wait_for(
+                                self._queue.get(), max(timeout, 0.0)))
+                    except asyncio.TimeoutError:
+                        break
             b = len(batch)
-            q = np.zeros((self.max_batch, batch[0][0].shape[0]), np.float32)
-            for i, (qi, _) in enumerate(batch):
-                q[i] = qi
+            with obs.span("frontend.pad", batch=seq):
+                q = np.zeros((self.max_batch, batch[0][0].shape[0]),
+                             np.float32)
+                for i, (qi, _, _) in enumerate(batch):
+                    q[i] = qi
             try:
-                sims, ids, _stats = await loop.run_in_executor(
-                    self._pool, self._search, q)
-                self.n_batches += 1
-                self.n_queries += b
-                for i, (_, fut) in enumerate(batch):
-                    if not fut.done():
-                        fut.set_result((sims[i], ids[i]))
+                with obs.span("frontend.device", batch=seq):
+                    call = (self._search, q, on)
+                    if on:
+                        handed = time.perf_counter_ns()
+                        # the device thread's spans sit in this one
+                        call = (contextvars.copy_context().run,) + call
+                    running = loop.run_in_executor(self._pool, *call)
+                    if on:
+                        # recorded while the device thread works
+                        for _, _, queued in batch:
+                            if queued:
+                                obs.record("frontend.queue_wait", queued,
+                                           handed, batch=seq)
+                    sims, ids, _stats, back = await running
+                    if on:
+                        obs.record("frontend.hop", back,
+                                   time.perf_counter_ns())
+                with obs.span("frontend.resolve", batch=seq):
+                    self.n_batches += 1
+                    self.n_queries += b
+                    for i, (_, fut, _) in enumerate(batch):
+                        if not fut.done():
+                            fut.set_result((sims[i], ids[i]))
             except Exception as e:                    # noqa: BLE001
-                for _, fut in batch:
+                for _, fut, _ in batch:
                     if not fut.done():
                         fut.set_exception(e)
             finally:
                 for _ in batch:
                     self._queue.task_done()
 
-    def _search(self, q: np.ndarray):
+    def _search(self, q: np.ndarray, on: bool):
+        """The device thread's part of a microbatch: the search and the
+        host copy of its answers, and (while tracing) when it returned."""
         sims, ids, stats = self.engine.search(q, self.k)
-        return np.asarray(sims), np.asarray(ids), stats
+        with obs.span("frontend.fetch"):
+            sims, ids = np.asarray(sims), np.asarray(ids)
+        return sims, ids, stats, time.perf_counter_ns() if on else 0

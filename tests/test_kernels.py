@@ -17,7 +17,7 @@ def _raw_kernel(idx, q, k, **kw):
     surface: no τ warm-start, natural block order) -> (sims, ids,
     mean computed-tile fraction)."""
     qn, qp = prep_queries(idx, jnp.asarray(q))
-    sims, pos, computed, _ = kernel_search(idx, qn, qp, k, **kw)
+    sims, pos, computed, _, _ = kernel_search(idx, qn, qp, k, **kw)
     return sims, map_row_ids(idx.row_ids, pos), computed.mean()
 
 
@@ -104,7 +104,7 @@ def test_raw_kernel_interface(rng):
     bn = 64
     lo = dp.reshape(-1, bn, 4).min(1)
     hi = dp.reshape(-1, bn, 4).max(1)
-    s, i, computed, elem = pruned_topk(
+    s, i, computed, elem, _ = pruned_topk(
         jnp.asarray(q), jnp.asarray(db), jnp.asarray(qp), jnp.asarray(lo),
         jnp.asarray(hi), 256, k=4, bm=8, bn=bn, interpret=True)
     assert elem is None                     # element_stats off by default
@@ -125,7 +125,7 @@ def test_raw_kernel_element_counter(rng):
     bn = 64
     lo = dp.reshape(-1, bn, 8).min(1)
     hi = dp.reshape(-1, bn, 8).max(1)
-    s, i, computed, elem = pruned_topk(
+    s, i, computed, elem, _ = pruned_topk(
         jnp.asarray(q), jnp.asarray(db), jnp.asarray(qp), jnp.asarray(lo),
         jnp.asarray(hi), 512, dp=jnp.asarray(dp), k=4, bm=8, bn=bn,
         interpret=True, element_stats=True)
@@ -149,9 +149,56 @@ def test_raw_kernel_k_beyond_one_lane_slab(rng):
     bn = 256
     lo = dp.reshape(-1, bn, 4).min(1)
     hi = dp.reshape(-1, bn, 4).max(1)
-    s, i, _, _ = pruned_topk(
+    s, i, _, _, _ = pruned_topk(
         jnp.asarray(q), jnp.asarray(db), jnp.asarray(qp), jnp.asarray(lo),
         jnp.asarray(hi), 512, k=150, bm=8, bn=bn, interpret=True)
     sref, iref = cref.brute_force_knn(q, db, 150)
     np.testing.assert_allclose(np.asarray(s), sref, atol=3e-5)
     assert (np.sort(np.asarray(i), 1) == np.sort(iref, 1)).mean() > 0.98
+
+
+def _replay_merge_rounds(qn, db, valid, k, bm, bn):
+    """The kernel's merge rule in NumPy, every tile computed in natural
+    order from ``-inf`` seeds: each round moves every row's best remaining
+    tile score into its k slots while any row's best remaining score beats
+    its k-th best.  Returns rounds summed over tiles / tiles."""
+    scores = np.where(valid[None, :], qn @ db.T, -np.inf)
+    rounds = tiles = 0
+    for a in range(0, len(qn), bm):
+        top = np.full((min(bm, len(qn) - a), k), -np.inf, np.float32)
+        for b in range(0, db.shape[0], bn):
+            s = scores[a:a + bm, b:b + bn].copy()
+            tiles += 1
+            while True:
+                best, kth = s.max(axis=1), top.min(axis=1)
+                take = np.flatnonzero(best > kth)
+                if not len(take):
+                    break
+                rounds += 1
+                for r in take:
+                    top[r, np.argmin(top[r])] = best[r]
+                    s[r, np.argmax(s[r])] = -np.inf
+    return rounds / tiles
+
+
+@pytest.mark.parametrize("k", [1, 10, 48])
+def test_merge_rounds_match_a_replay_of_the_merge_rule(k, rng):
+    """``SearchStats.merge_rounds`` counts the kernel's merge rounds: with
+    pruning, warm start, best-first order and the query sort off, every
+    tile is merged from empty slots in natural order, which NumPy replays
+    (1000 rows: the last tile holds padding rows; 20 queries: the last
+    query tile holds padding queries)."""
+    from repro.search import SearchEngine
+
+    db = clustered(rng, 1000, 16)
+    q = clustered(rng, 20, 16)
+    eng = SearchEngine.build(db, n_pivots=4, block_size=128,
+                             backend="kernel", bm=8, bn=256,
+                             warm_start=False, best_first=False,
+                             sort_queries=False)
+    _, _, stats = eng.search(jnp.asarray(q), k, prune=False)
+    qn, _ = prep_queries(eng.index, jnp.asarray(q))
+    want = _replay_merge_rounds(np.asarray(qn), np.asarray(eng.index.db),
+                                np.asarray(eng.index.valid), k, 8, 256)
+    assert want > 0
+    assert float(stats.merge_rounds) == pytest.approx(want, rel=1e-6)

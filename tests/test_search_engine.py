@@ -282,6 +282,9 @@ def test_stats_fraction_invariants(rng):
             assert stats.tree_node_eval_frac is not None
         if backend != "kernel":
             assert stats.tile_computed_frac is None, backend
+            assert stats.merge_rounds is None, backend
+        else:
+            assert float(stats.merge_rounds) >= 0.0
         # element stats off: None, not 0.0 (brute reports 0.0 when ON —
         # the stage ran and pruned nothing, by definition)
         _, _, off = eng.search(jnp.asarray(db[:5]), 6, element_stats=False)
