@@ -34,6 +34,12 @@ The HBM->VMEM copy of a pruned tile is not yet elided.
 
 Alignment: BM a multiple of 8, BN a multiple of 128 on TPU; D is kept
 whole in VMEM (q tile + db tile at BM=128, BN=256, D=768, f32 = 1.1 MiB).
+
+Orientation (:func:`db_layout`): the TPU runtime stores an f32 ``[N, D]``
+array column-major when D is not a multiple of 128, so the kernel then
+reads the corpus as ``db.T`` (``[D, N]``, a bitcast of the stored buffer)
+in ``(D, BN)`` blocks.  Read as ``[N, D]`` it would be laid out again,
+lane-padded, on every call.
 """
 from __future__ import annotations
 
@@ -51,8 +57,21 @@ _NEG_INF = float("-inf")
 _LANES = 128
 
 
+def db_layout(d: int) -> str:
+    """The orientation the kernels read a stored ``[N, d]`` f32 corpus in.
+
+    ``"rows"`` (``[N, d]`` blocks) where ``d`` is a multiple of 128, which
+    the runtime stores row-major; ``"cols"`` (``db.T``, ``[d, N]`` blocks)
+    otherwise, where it stores the array column-major, ``{0,1}`` (AOT
+    compile for v5e: widths 64, 96, 100 and 200 are column-major; 128,
+    256, 384, 768 and 1024 row-major).
+    """
+    return "rows" if d % _LANES == 0 else "cols"
+
+
 def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
-                 element_stats: bool, use_cap: bool = False):
+                 element_stats: bool, use_cap: bool = False,
+                 cols: bool = False):
     def kernel(order_ref, mvalid_ref, tau_ref, qn_ref, db_ref, qp_ref,
                lo_ref, hi_ref, rv_ref, *rest):
         rest = list(rest)
@@ -146,8 +165,10 @@ def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
 
         @pl.when(needed)
         def _compute():
+            # db tile [BN, D] (rows) or [D, BN] (cols): contract over D
             scores = jax.lax.dot_general(
-                qn_ref[...], db_ref[...], (((1,), (1,)), ((), ())),
+                qn_ref[...], db_ref[...], (((1,), (0 if cols else 1,)),
+                                           ((), ())),
                 precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32,
             )                                             # [BM, BN]
@@ -191,7 +212,7 @@ def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
 @functools.partial(
     jax.jit,
     static_argnames=("k", "bm", "bn", "margin", "prune", "interpret",
-                     "element_stats"),
+                     "element_stats", "db_layout"),
 )
 def pruned_topk(
     qn: Array,
@@ -214,12 +235,14 @@ def pruned_topk(
     prune: bool = True,
     interpret: bool = False,
     element_stats: bool = False,
+    db_layout: str = "rows",
 ):
     """Fused exact top-k with block pruning.
 
     Args:
       qn:      [M, D] L2-normalized queries.
-      db:      [N, D] L2-normalized database (padding rows at the END).
+      db:      [N, D] L2-normalized database (padding rows at the END), or
+               its transpose [D, N] when ``db_layout="cols"``.
       qp:      [M, P] query-pivot similarities.
       dp_min/dp_max: [N // bn, P] pivot intervals at KERNEL tile granularity
                (use :func:`repro.search.backends.coarsen_intervals`).
@@ -250,6 +273,9 @@ def pruned_topk(
       element_stats: also count, per visited tile, the (query, row) pairs
                whose individual Eq. 13 bound is below the running τ — the
                backend-uniform ``elem_prune_frac`` numerator.
+      db_layout: ``"rows"`` or ``"cols"``, the orientation ``db`` is
+               given in; :func:`db_layout` picks the one that reads a
+               stored corpus without relayout.
 
     Returns (sims [M, k] f32 descending, idx [M, k] i32 positions into db,
     computed [M_tiles, N_tiles] i32 — which db tiles did real work, indexed
@@ -259,7 +285,9 @@ def pruned_topk(
     summed over its computed tiles).
     """
     m, d = qn.shape
-    n = db.shape[0]
+    cols = db_layout == "cols"
+    assert cols or db_layout == "rows", db_layout
+    n = db.shape[1] if cols else db.shape[0]
     p = qp.shape[1]
     assert n % bn == 0 and dp_min.shape[0] == n // bn, (n, bn, dp_min.shape)
     assert k <= bn, "k must fit in one db tile"
@@ -291,7 +319,7 @@ def pruned_topk(
     assert block_order.shape == grid, (block_order.shape, grid)
     use_cap = ub_cap is not None
     kern = _make_kernel(k, bm, bn, margin, prune, element_stats,
-                        use_cap=use_cap)
+                        use_cap=use_cap, cols=cols)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out_shape = [
         jax.ShapeDtypeStruct((mp, kp), jnp.float32),
@@ -302,7 +330,9 @@ def pruned_topk(
     in_specs = [
         pl.BlockSpec((bm, 1), lambda i, j, ord_, mv_: (i, 0)),  # tau seeds
         pl.BlockSpec((bm, d), lambda i, j, ord_, mv_: (i, 0)),  # qn
-        pl.BlockSpec((bn, d), lambda i, j, ord_, mv_: (ord_[i, j], 0)),  # db
+        (pl.BlockSpec((d, bn), lambda i, j, ord_, mv_: (0, ord_[i, j]))
+         if cols else
+         pl.BlockSpec((bn, d), lambda i, j, ord_, mv_: (ord_[i, j], 0))),  # db
         pl.BlockSpec((bm, p), lambda i, j, ord_, mv_: (i, 0)),  # qp
         pl.BlockSpec((None, 1, p),
                      lambda i, j, ord_, mv_: (ord_[i, j], 0, 0)),  # lo

@@ -34,7 +34,7 @@ from repro.core.bounds import ub_mult
 from repro.core.index import (BlockIndex, block_upper_bound,
                               multipivot_block_cap)
 from repro.core.pivots import normalize
-from repro.kernels import cosine_topk
+from repro.kernels import cosine_topk, tile_prescan
 from repro.kernels import ref as kref
 
 __all__ = [
@@ -376,9 +376,16 @@ def kernel_search(
     block granularity, coarsens it to kernel tiles (max over merged
     blocks — still a valid tile bound), and hands it to the kernel as the
     extra per-(query-tile, db-tile) bound operand.
+
+    Where the runtime stores the corpus column-major (a width that is not a
+    multiple of 128, :func:`cosine_topk.db_layout`), the kernel and the τ
+    prescan (:mod:`repro.kernels.tile_prescan`) read ``index.db.T``, a
+    bitcast of the stored buffer, so no call lays the corpus out again.
     """
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
+    layout = cosine_topk.db_layout(index.db.shape[-1])
+    db = index.db.T if layout == "cols" else index.db
     bn = _resolve_bn(index, bn)
     factor = bn // index.block_size
     lo, hi = coarsen_intervals(index.dp_min, index.dp_max, factor)
@@ -402,12 +409,15 @@ def kernel_search(
     tau_init = None
     if warm_start:
         with jax.named_scope("prescan"):
-            db_tiles = index.db.reshape(-1, bn, index.db.shape[-1])
-            valid_tiles = index.valid.reshape(-1, bn)
-            n_pre = prescan_blocks(k, bn, db_tiles.shape[0],
-                                   warm_start_blocks)
-            tau_init = tau_warm_start(qn, db_tiles, valid_tiles, ub, k,
-                                      n_pre)
+            n_pre = prescan_blocks(k, bn, lo.shape[0], warm_start_blocks)
+            if layout == "cols":
+                tau_init = tile_prescan.tau_prescan(
+                    qn, db, index.valid, ub, k=k, n_pre=n_pre, bn=bn,
+                    interpret=interpret)
+            else:
+                tau_init = tau_warm_start(
+                    qn, index.db.reshape(-1, bn, index.db.shape[-1]),
+                    index.valid.reshape(-1, bn), ub, k, n_pre)
     block_order = None
     if best_first:
         with jax.named_scope("order"):
@@ -420,12 +430,13 @@ def kernel_search(
 
     with jax.named_scope("pruned_topk"):
         sims, pos, computed, elem, rounds = cosine_topk.pruned_topk(
-            qn, index.db, qp, lo, hi, n_valid,
+            qn, db, qp, lo, hi, n_valid,
             tau_init=tau_init, block_order=block_order,
             dp=index.dp if element_stats else None, ub_cap=ub_cap,
             row_valid=index.valid,
             k=k, bm=bm, bn=bn, margin=margin, prune=prune,
             interpret=interpret, element_stats=element_stats,
+            db_layout=layout,
         )
     if sort_queries:
         with jax.named_scope("unsort"):

@@ -487,8 +487,11 @@ class SearchEngine:
         if not hasattr(queries, "shape"):
             queries = jnp.asarray(queries)
         kk = min(k, self.n_slots)
+        # which orientation the kernel reads the stored corpus in
+        layout = ({"db_layout": _bk.cosine_topk.db_layout(
+            self.index.db.shape[-1])} if self.backend_name == "kernel" else {})
         with obs.span("engine.search", backend=self.backend_name, k=k,
-                      m=int(queries.shape[0])) as call:
+                      m=int(queries.shape[0]), **layout) as call:
             traces_before = self._traces
             with obs.span("engine.dispatch"):
                 fused = self._fused_callable(queries, kk, prune,

@@ -7,8 +7,10 @@ from repro.core import ref as cref
 from repro.core.index import build_index
 from repro.kernels import ref as kref
 from repro.kernels.bound_prune import block_bounds as bp_kernel
-from repro.kernels.cosine_topk import pruned_topk
-from repro.search.backends import kernel_search, map_row_ids, prep_queries
+from repro.kernels.cosine_topk import db_layout, pruned_topk
+from repro.kernels.tile_prescan import tau_prescan
+from repro.search.backends import (kernel_search, map_row_ids, prep_queries,
+                                   tau_warm_start)
 from tests.conftest import clustered
 
 
@@ -202,3 +204,72 @@ def test_merge_rounds_match_a_replay_of_the_merge_rule(k, rng):
                                 np.asarray(eng.index.valid), k, 8, 256)
     assert want > 0
     assert float(stats.merge_rounds) == pytest.approx(want, rel=1e-6)
+
+
+@pytest.mark.parametrize("dead", ["scattered", "sparse_tiles"])
+@pytest.mark.parametrize("k", [10, 100])
+@pytest.mark.parametrize("d", [96, 100])
+def test_column_layout_is_exact(d, k, dead, rng):
+    """A width that is not a multiple of 128 is read as ``db.T``: the
+    τ prescan kernel gives ``tau_warm_start``'s seeds for the same tiles,
+    and the kernel run on ``[D, N]`` blocks returns what it returns on
+    ``[N, D]`` blocks, which is brute force over the valid rows.  Rows are
+    tombstoned in place (scattered, or all but 60 of three of the four
+    tiles, so that at k=100 a query's one prescanned tile can hold fewer
+    than k valid rows: τ -inf); 20 queries leave the last query tile
+    padded; d=100 widens the prescan to two tiles; the kernel visits the
+    tiles in a shuffled order."""
+    n, m, p, bm, bn = 1024, 20, 8, 8, 256
+    nt, n_pre = n // bn, 1 if d == 96 else 2
+    assert db_layout(d) == "cols"
+    db = clustered(rng, n, d)
+    db = db[np.argsort(db @ db[0])]        # tiles of differing bounds
+    q = cref.normalize(db[rng.choice(n, m, replace=False)]
+                       + 0.02 * rng.normal(size=(m, d))).astype(np.float32)
+    valid = np.ones(n, bool)
+    if dead == "scattered":
+        valid[rng.choice(n, n // 10, replace=False)] = False
+    else:
+        for t in range(nt - 1):
+            valid[t * bn + 60:(t + 1) * bn] = False
+    piv = db[rng.choice(n, p, replace=False)]
+    qp, dp = q @ piv.T, db @ piv.T
+    lo = dp.reshape(nt, bn, p).min(1)
+    hi = dp.reshape(nt, bn, p).max(1)
+    qn, dbj, vj = jnp.asarray(q), jnp.asarray(db), jnp.asarray(valid)
+    ub = kref.block_bounds(jnp.asarray(qp), jnp.asarray(lo), jnp.asarray(hi))
+
+    want_tau = np.asarray(tau_warm_start(
+        qn, dbj.reshape(nt, bn, d), vj.reshape(nt, bn), ub, k, n_pre))
+    tau = np.asarray(tau_prescan(qn, dbj.T, vj, ub, k=k, n_pre=n_pre, bn=bn,
+                                 interpret=True))
+    np.testing.assert_array_equal(np.isneginf(tau), np.isneginf(want_tau))
+    fin = np.isfinite(want_tau)
+    np.testing.assert_allclose(tau[fin], want_tau[fin], rtol=0, atol=1e-6)
+    if dead == "sparse_tiles" and k == 100 and n_pre == 1:
+        assert np.isneginf(tau).any()
+    # fewer candidates than k in the whole prescan: no seed
+    assert np.isneginf(np.asarray(tau_prescan(
+        qn, dbj.T, vj, ub, k=n_pre * bn + 1, n_pre=n_pre, bn=bn,
+        interpret=True))).all()
+
+    # any visiting order is exact; a shuffled one checks the tile ids
+    order = jnp.asarray(np.stack([rng.permutation(nt) for _ in range(3)]),
+                        jnp.int32)
+    out = {}
+    for layout, operand in (("rows", dbj), ("cols", dbj.T)):
+        s, i, _, _, _ = pruned_topk(
+            qn, operand, jnp.asarray(qp), jnp.asarray(lo), jnp.asarray(hi),
+            int(valid.sum()), tau_init=jnp.asarray(tau), block_order=order,
+            row_valid=vj, k=k, bm=bm, bn=bn, interpret=True,
+            db_layout=layout)
+        out[layout] = np.asarray(s), np.asarray(i)
+    ref = np.where(valid[None, :], q.astype(np.float64) @ db.T.astype(
+        np.float64), -np.inf)
+    ref_i = np.argsort(-ref, axis=1)[:, :k]
+    ref_s = np.take_along_axis(ref, ref_i, axis=1)
+    s_cols, i_cols = out["cols"]
+    np.testing.assert_array_equal(i_cols, out["rows"][1])
+    np.testing.assert_allclose(s_cols, out["rows"][0], rtol=0, atol=1e-6)
+    np.testing.assert_array_equal(np.sort(i_cols, 1), np.sort(ref_i, 1))
+    np.testing.assert_allclose(s_cols, ref_s, rtol=0, atol=1e-6)

@@ -96,6 +96,29 @@ def test_spans_land_by_name_on_the_host_plane_of_the_trace(rng, tmp_path):
     assert search.ids["stats"].n_queries == 4
 
 
+@pytest.mark.parametrize("backend,d,want", [("kernel", 16, "cols"),
+                                             ("kernel", 128, "rows"),
+                                             ("scan", 16, None)])
+def test_engine_search_names_the_kernels_db_layout(backend, d, want, rng,
+                                                   tmp_path):
+    """The kernel backend reads a corpus whose width is not a multiple of
+    128 as ``db.T``; ``engine.search`` says which orientation ran, on its
+    profiler event and its record.  Other backends give no ``db_layout``."""
+    eng = _engine(rng, d=d, backend=backend)
+    q = jnp.asarray(np.asarray(eng.index.db[:4]))
+    jax.block_until_ready(eng.search(q, 5)[:2])        # warm: no trace
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(eng.search(q, 5)[:2])
+    (search,) = obs.records("engine.search")
+    assert search.ids.get("db_layout") == want
+    (path,) = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                        recursive=True)
+    stats = [dict(ev.stats) for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host") for line in plane.lines
+             for ev in line.events if ev.name == "engine.search"]
+    assert [st.get("db_layout") for st in stats] == [want]
+
+
 def test_engine_traces_counts_one_trace_per_new_shape(rng):
     eng = _engine(rng)
     q = jnp.asarray(np.asarray(eng.index.db[:6]))

@@ -13,6 +13,10 @@ this file loads the TPU compiler.  The persistent compilation cache is off
 around the compiles: an entry written for a described chip cannot be read
 back here.
 """
+import math
+import re
+import types
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.index import BlockIndex
 from repro.kernels import bound_prune, cosine_topk, leaf_gather
+from repro.search.backends import get_backend
 
 D, BN, P = 768, 256, 16       # MS MARCO passage embeddings, kernel tile, pivots
 M = 256                       # queries per search batch
@@ -123,3 +128,62 @@ def test_gathered_topk_compiles_for_v5e(k, shape, no_persistent_cache):
     text = _compiled_text(leaves, index, shape(n_keep, dtype=jnp.int32),
                           shape(M, D), shape(M, P), shape(M))
     assert "tpu_custom_call" in text
+
+
+def _db_consumers(hlo: str, db_shape: str) -> list[tuple[str, str, str]]:
+    """``(name, opcode, result type)`` of every instruction of the entry
+    computation that reads the ``db_shape`` parameter, through bitcasts
+    (which are followed, not listed)."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    inst = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\((.*)$")
+    lines = [m.groups() for m in map(inst.match, entry.splitlines()) if m]
+    names = {n for n, ty, op, _ in lines
+             if op == "parameter" and ty.startswith(db_shape + "{")}
+    assert len(names) == 1, names
+    out = []
+    for n, ty, op, rest in lines:
+        args = set(re.findall(r"%[\w.\-]+", rest.split(")")[0]))
+        if args & names:
+            if op == "bitcast":
+                names.add(n)
+            else:
+                out.append((n, op, ty))
+    return out
+
+
+@pytest.mark.parametrize("d", [96, 768])
+def test_fused_kernel_search_reads_the_stored_corpus(d, shape,
+                                                     no_persistent_cache):
+    """The engine's one-dispatch kernel search (query prep, bound, τ
+    prescan, best-first order, ``pruned_topk``, id map), as the engine
+    builds it with its defaults, lays no part of the corpus out again:
+    the database parameter feeds bitcasts and the kernels' custom calls,
+    and nothing corpus-sized besides.  At d=96 the runtime stores
+    ``[N, 96]`` column-major, so the kernel and the prescan read ``db.T``.
+    At d=768 the stored row-major corpus feeds the kernel directly, and
+    the XLA prescan gathers ``m`` 256-row tiles (``[m, 256, d]``); with
+    2^20 rows that is an eighth of the corpus at m=256, so no temporary
+    reaches it."""
+    n, p, bs, k = 1 << 20, P, 128, 10
+    eng = types.SimpleNamespace(
+        _note_trace=lambda: None, bm=cosine_topk.DEFAULT_BM, bn=BN,
+        sort_queries=True, warm_start=True, best_first=True, margin=4e-7,
+        interpret=False, warm_start_blocks=None, n_pivots=0)
+    fused = get_backend("kernel").make_fused(
+        eng, k, prune=True, element_stats=False, donate=False)
+    nb = n // bs
+    index = BlockIndex(shape(n, d), shape(n, p), shape(p, d), shape(nb, p),
+                       shape(nb, p), shape(n, dtype=jnp.bool_),
+                       shape(n, dtype=jnp.int32))
+    compiled = fused.lower(index, shape(M, d)).compile()
+    db_bytes = n * d * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < db_bytes // 8, (temp, db_bytes)
+    consumers = _db_consumers(compiled.as_text(), f"f32[{n},{d}]")
+    kernels = [c for c in consumers if c[1] == "custom-call"]
+    assert len(kernels) == (1 if d % 128 == 0 else 2)    # + the prescan
+    for name, op, ty in consumers:
+        assert op in ("custom-call", "fusion"), (name, op, ty)
+        if op == "fusion":
+            dims = re.match(r"f32\[([\d,]+)\]", ty).group(1).split(",")
+            assert math.prod(map(int, dims)) * 4 <= db_bytes // 8, (name, ty)
