@@ -8,14 +8,16 @@ keeps build embarrassingly parallel and, because a shard covers a narrower
 slice of the sphere, makes the local Eq. 13 bounds slightly tighter than
 global pivots would be.
 
-Search is the shard-local *scan* inner loop (so the engine's τ warm-start
-and best-first ordering apply per shard) followed by a tiny global merge:
+Search is the shard-local inner loop a one-chip engine would run over the
+shard — the fused Pallas kernel search (:func:`~repro.search.backends.
+kernel_search`), so the engine's τ warm-start and best-first ordering apply
+per shard — followed by a tiny global merge:
 ``all_gather`` of the per-shard (k sims, k global ids) — ``O(devices * k)``
 bytes, negligible next to the avoided score matmuls — then ``lax.top_k``.
 Exactness is preserved: every shard returns its true local top-k and the
 union of local top-k sets contains the global top-k.
 
-With per-shard pivot trees (``SearchEngine(tree_shards=...)``) the local
+With per-shard pivot trees (``SearchEngine(tree_shards=...)``) a local leaf
 scan is preceded by the transitive Eq. 13 descent over each shard's own
 tree, pruning against a **global** τ assembled from every shard's
 warm-start candidates by a second tiny collective (mask-carrying top-k
@@ -218,7 +220,13 @@ def build_sharded_index_local(
 
     ``global_rows`` is the TOTAL logical row count across all hosts
     (metadata every launcher knows; it fixes the rows-per-shard split and
-    the global row-id offsets).  The result is placed like
+    the global row-id offsets).  ``db_local`` may also hold every one of
+    its shards whole, ``ceil(global_rows / n_shards)`` rows each — a
+    device array split evenly by rows over the mesh, whose length is
+    ``global_rows`` rounded up to a multiple of the shard count: rows at
+    and past ``global_rows`` are then padding, marked invalid like the
+    tail of a short last shard, and each shard's rows are used where they
+    lie.  The result is placed like
     :func:`place_sharded_index` would place it — ``P(axis_names)`` over
     the flattened mesh axes — and is bit-identical, shard for shard, to
     ``build_sharded_index(full_db, n_shards)`` on the same rows: both
@@ -232,12 +240,15 @@ def build_sharded_index_local(
     per, owned = local_shard_rows(global_rows, mesh, axis_names)
     n_shards = int(np.prod([mesh.shape[a] for a in axis]))
     expected = sum(stop - start for _, start, stop in owned)
-    if db_local.shape[0] != expected:
+    # whole shards: the corpus rounded up to the shard count, padding last
+    whole = db_local.shape[0] == per * len(owned)
+    if db_local.shape[0] != expected and not whole:
         raise ValueError(
             f"db_local has {db_local.shape[0]} rows but this process's "
             f"shards {[s for s, _, _ in owned]} cover {expected} of the "
             f"{global_rows} global rows ({per} per shard across {n_shards} "
-            f"shards); slice the datastore with local_shard_rows()")
+            f"shards, {per * len(owned)} with the padding); slice the "
+            f"datastore with local_shard_rows()")
     mesh = auto_mesh(mesh)
     sh = NamedSharding(mesh, P(axis))
     device_of = {(idx[0].start or 0): dev for dev, idx
@@ -246,14 +257,15 @@ def build_sharded_index_local(
     shards, ofs = [], 0
     for s, start, stop in owned:
         cnt = stop - start
+        take = per if whole else cnt
         dev = device_of[s]
         # each shard is built on the device that will hold it: the rows
         # move there first, and every step of the build runs where its
         # input lives — no device builds or stacks another's shard
-        rows = _rows_on_device(db_local, ofs, ofs + cnt, dev)
-        ofs += cnt
-        if cnt < per:  # trailing short shard: pad with invalid zero rows
-            rows = jnp.pad(rows, ((0, per - cnt), (0, 0)))
+        rows = _rows_on_device(db_local, ofs, ofs + take, dev)
+        ofs += take
+        if take < per:  # trailing short shard: pad with invalid zero rows
+            rows = jnp.pad(rows, ((0, per - take), (0, 0)))
         with jax.default_device(dev):
             part = _build_shard_part(
                 rows, n_valid=cnt, row_offset=s * per, n_pivots=n_pivots,
@@ -288,24 +300,32 @@ def _rows_on_device(db_local, start: int, stop: int, dev):
 
 
 def sharded_search_local(index: BlockIndex, queries: Array, k: int, axis_names,
-                         *, prune: bool = True,
+                         *, inner: str = "scan", prune: bool = True,
                          warm_start: bool = False, best_first: bool = False,
                          warm_start_blocks: int | None = None,
                          element_stats: bool = False,
                          with_stats: bool = False,
                          tree=None, margin: float = 4e-7,
-                         n_pivots: int = 0):
-    """Body that runs inside ``shard_map``: local scan + global merge.
+                         n_pivots: int = 0, bm: int = 128,
+                         bn: int | None = None, sort_queries: bool = True,
+                         interpret: bool | None = None):
+    """Body that runs inside ``shard_map``: local search + global merge.
 
     ``index`` arrives with the leading shard axis of size 1 (this device's
-    shard); ``queries`` are replicated.  ``warm_start`` / ``best_first`` /
-    ``warm_start_blocks`` / ``element_stats`` are the engine policies,
-    applied to each shard's local scan (the τ prescan seeds from each
-    shard's own best-bound blocks — DESIGN.md §3.4).
+    shard); ``queries`` are replicated.  ``inner`` names the search each
+    shard runs over its flat local index, the same inner loop a one-chip
+    engine runs: ``"kernel"`` (:func:`~repro.search.backends.kernel_search`,
+    the fused Pallas kernel; ``bm`` / ``bn`` / ``sort_queries`` /
+    ``interpret`` are its tile options), ``"brute"`` or ``"scan"`` (the
+    default).
+    ``warm_start`` / ``best_first`` / ``warm_start_blocks`` /
+    ``element_stats`` / ``n_pivots`` are the engine policies, applied to
+    each shard's local search (the τ prescan seeds from each shard's own
+    best-bound blocks — DESIGN.md §3.4).
 
     With ``tree`` (a :class:`~repro.search.tree.ShardTreeArrays`, leading
     shard axis of size 1) each shard instead runs the transitive Eq. 13
-    descent over its *local* pivot tree before the leaf scan — DESIGN.md
+    descent over its *local* pivot tree before a leaf scan — DESIGN.md
     §3.6.  The τ the descent prunes against is **global**: every shard's
     beam warm-start candidates are merged with the mask-carrying top-k
     all-gather and the k-th best of the union is broadcast back, so each
@@ -316,97 +336,141 @@ def sharded_search_local(index: BlockIndex, queries: Array, k: int, axis_names,
     Everything stays statically shaped — the surviving leaves are a
     boolean mask into the local scan, not a compaction — which is what
     ``shard_map`` tracing requires.
+
+    With ``with_stats`` the result gains the raw stats dict of the inner
+    loop (as the one-chip backends report it), every fraction
+    psum-weighted: sums of per-shard counts over sums of per-shard
+    denominators, so unevenly filled shards weigh correctly.
     """
-    from repro.dist.collectives import global_tau_merge, topk_allgather_merge
-    from repro.search.backends import map_row_ids, prep_queries, scan_search
+    from repro.dist.collectives import topk_allgather_merge
+    from repro.search import backends as bk
     local = jax.tree.map(lambda x: x[0], index)
-    qn, qp = prep_queries(local, queries)
-    m = qn.shape[0]
-    if tree is None:
-        sims, pos, blk_pruned, elem_pruned = scan_search(
-            local, qn, qp, k, prune=prune, margin=margin,
-            warm_start=warm_start, best_first=best_first,
-            warm_start_blocks=warm_start_blocks, element_stats=element_stats,
-            n_pivots=n_pivots)
-        tree_pruned = evals = None
-    else:
-        # the descent is pure masking work with prune off — the backend
-        # only hands a tree in when pruning is on
-        assert prune, "tree descent requires prune=True"
-        from repro.search.tree import TreeIndex, _seed_and_descend
-        ltree = TreeIndex(local, tree.node_lo[0], tree.node_hi[0],
-                          tree.node_valid[0])
-        # the one exactness-critical seed -> descend -> flat-reseed
-        # sequence, shared with the single-device tree backend; the merge
-        # hook turns each shard's beam candidates into ONE global τ per
-        # query (mask-carrying, so shards holding < k candidates still
-        # contribute theirs) — §3.6
-        tau0, leaf_alive, leaf_ub, evals = _seed_and_descend(
-            ltree, qn, qp, k, warm_start=warm_start,
-            warm_start_blocks=warm_start_blocks, margin=margin,
-            tau_merge=lambda s, v: global_tau_merge(s, v, k, axis_names))
-        if n_pivots > 0:
-            # eq13_multi over the LOCAL shard tables (pivots — and so the
-            # joint basis — were always shard-local); the leaf scan below
-            # consumes the tightened bound matrix unchanged
-            from repro.core.index import multipivot_block_cap
-            leaf_ub = jnp.minimum(
-                leaf_ub, multipivot_block_cap(local, qn, n_pivots=n_pivots))
-        sims, pos, blk_pruned, elem_pruned = scan_search(
-            local, qn, qp, k, margin=margin, warm_start=False,
-            best_first=best_first, element_stats=element_stats,
-            tau0=tau0, ub_all=leaf_ub, leaf_mask=leaf_alive)
-        tree_pruned = (~leaf_alive).sum().astype(jnp.float32)
-    # build_sharded_index bakes GLOBAL ids into row_ids — no rank arithmetic
-    gids = map_row_ids(local.row_ids, pos)
-    # tiny collective: O(devices * k) candidates
-    merged = topk_allgather_merge(sims, gids, k, axis_names)
+
+    def total(x):
+        return jax.lax.psum(jnp.asarray(x, jnp.float32), axis_names)
+
+    # each branch leaves ``stats``, a thunk of its inner loop's
+    # psum-weighted raw stats (called only with ``with_stats``), and
+    # ``elem``, this shard's count of element-pruned (query, row) pairs
+    with jax.named_scope("shard_search"):
+        qn, qp = bk.prep_queries(local, queries)
+        m = qn.shape[0]
+        if tree is not None:
+            sims, pos, stats, elem = _tree_shard_search(
+                local, tree, qn, qp, k, axis_names, total,
+                warm_start=warm_start, best_first=best_first,
+                warm_start_blocks=warm_start_blocks,
+                element_stats=element_stats, margin=margin,
+                n_pivots=n_pivots)
+        elif inner == "kernel":
+            sims, pos, computed, elem, rounds = bk.kernel_search(
+                local, qn, qp, k, bm=bm, bn=bn, prune=prune,
+                sort_queries=sort_queries, warm_start=warm_start,
+                best_first=best_first, margin=margin, interpret=interpret,
+                element_stats=element_stats,
+                warm_start_blocks=warm_start_blocks, n_pivots=n_pivots)
+            elem = elem.sum() if element_stats else 0.0
+
+            def stats():                # (query tile, kernel tile) pairs
+                done = total(computed.sum())
+                frac = done / total(computed.size)
+                return {"block_prune_frac": 1.0 - frac,
+                        "tile_computed_frac": frac,
+                        "merge_rounds": (total(rounds.sum())
+                                         / jnp.maximum(done, 1.0))}
+        elif inner == "brute":
+            sims, pos = bk.brute_search(local, qn, k)
+            elem = 0.0
+
+            def stats():                # brute skips nothing
+                return {"block_prune_frac": jnp.float32(0.0)}
+        else:
+            sims, pos, blk_pruned, elem = bk.scan_search(
+                local, qn, qp, k, prune=prune, margin=margin,
+                warm_start=warm_start, best_first=best_first,
+                warm_start_blocks=warm_start_blocks,
+                element_stats=element_stats, n_pivots=n_pivots)
+
+            def stats():                # (query, index block) pairs
+                return {"block_prune_frac": (
+                    total(blk_pruned) / (m * total(local.n_blocks)))}
+        # build_sharded_index bakes GLOBAL ids into row_ids — no rank
+        # arithmetic
+        gids = bk.map_row_ids(local.row_ids, pos)
+    with jax.named_scope("merge"):
+        # tiny collective: O(devices * k) candidates
+        merged = topk_allgather_merge(sims, gids, k, axis_names)
     if not with_stats:
         return merged
-    # psum-weighted aggregates: sums of per-shard counts over sums of
-    # per-shard denominators, so unevenly-filled shards weight correctly
-    # (the bug class tests/test_sharded_tree.py pins down)
-    nb_sum = jax.lax.psum(jnp.float32(local.n_blocks), axis_names)
-    frac = jax.lax.psum(blk_pruned, axis_names) / (m * nb_sum)
-    n_valid = local.valid.sum().astype(jnp.float32)
-    efrac = (jax.lax.psum(elem_pruned, axis_names)
-             / jnp.maximum(1.0, m * jax.lax.psum(n_valid, axis_names)))
-    if tree is None:
-        return merged + (frac, efrac)
-    tfrac = jax.lax.psum(tree_pruned, axis_names) / (m * nb_sum)
-    nodes = jax.lax.psum(ltree.node_valid.sum().astype(jnp.float32),
-                         axis_names)
-    evfrac = jax.lax.psum(evals, axis_names) / jnp.maximum(1.0, m * nodes)
-    return merged + (frac, efrac, tfrac, evfrac)
+    raw = stats()
+    if element_stats:
+        raw["elem_prune_frac"] = total(elem) / jnp.maximum(
+            1.0, m * total(local.valid.sum()))
+    return merged + (raw,)
+
+
+def _tree_shard_search(local, tree, qn, qp, k, axis_names, total, *,
+                       warm_start, best_first, warm_start_blocks,
+                       element_stats, margin, n_pivots):
+    """One shard's descent over its local pivot tree, seeded by the global
+    τ, then the masked leaf scan (DESIGN.md §3.6)."""
+    from repro.dist.collectives import global_tau_merge
+    from repro.search.backends import scan_search
+    from repro.search.tree import TreeIndex, _seed_and_descend
+    ltree = TreeIndex(local, tree.node_lo[0], tree.node_hi[0],
+                      tree.node_valid[0])
+    # the one exactness-critical seed -> descend -> flat-reseed sequence,
+    # shared with the single-device tree backend; the merge hook turns each
+    # shard's beam candidates into ONE global τ per query (mask-carrying,
+    # so shards holding < k candidates still contribute theirs) — §3.6
+    tau0, leaf_alive, leaf_ub, evals = _seed_and_descend(
+        ltree, qn, qp, k, warm_start=warm_start,
+        warm_start_blocks=warm_start_blocks, margin=margin,
+        tau_merge=lambda s, v: global_tau_merge(s, v, k, axis_names))
+    if n_pivots > 0:
+        # eq13_multi over the LOCAL shard tables (pivots — and so the joint
+        # basis — were always shard-local); the leaf scan below consumes
+        # the tightened bound matrix unchanged
+        from repro.core.index import multipivot_block_cap
+        leaf_ub = jnp.minimum(
+            leaf_ub, multipivot_block_cap(local, qn, n_pivots=n_pivots))
+    sims, pos, blk_pruned, elem_pruned = scan_search(
+        local, qn, qp, k, margin=margin, warm_start=False,
+        best_first=best_first, element_stats=element_stats,
+        tau0=tau0, ub_all=leaf_ub, leaf_mask=leaf_alive)
+
+    def stats():                        # (query, index block) pairs
+        pairs = qn.shape[0] * total(local.n_blocks)
+        return {"block_prune_frac": total(blk_pruned) / pairs,
+                "tree_prune_frac": total((~leaf_alive).sum()) / pairs,
+                "tree_node_eval_frac": total(evals) / jnp.maximum(
+                    1.0, qn.shape[0] * total(ltree.node_valid.sum()))}
+
+    return sims, pos, stats, elem_pruned
 
 
 def make_sharded_search(mesh: Mesh, axis_names: tuple[str, ...] | None = None,
-                        *, prune: bool = True,
-                        warm_start: bool = False, best_first: bool = False,
-                        warm_start_blocks: int | None = None,
-                        element_stats: bool = False,
-                        with_stats: bool = False,
-                        margin: float = 4e-7,
-                        n_pivots: int = 0,
-                        trace_hook=None):
-    """Build a jitted ``(index, queries, k[, tree]) -> (sims, gids)`` closure.
+                        *, with_stats: bool = False, trace_hook=None,
+                        **search_kw):
+    """Build a jitted ``(index, queries, k[, tree]) -> (sims, gids[, raw])``
+    closure: :func:`sharded_search_local` in ``shard_map`` over ``mesh``.
 
-    ``trace_hook`` (optional zero-arg callable) is invoked inside the
-    traced body, i.e. exactly once per trace+compile and never on cached
-    dispatches — the engine passes its retrace counter so the sharded
-    path's ``SearchStats.retraces`` is as observable as the flat ones.
+    ``search_kw`` are :func:`sharded_search_local`'s options (``inner``,
+    the engine policies and the kernel's tile options).  ``trace_hook``
+    (optional zero-arg callable) is invoked inside the traced body, i.e.
+    exactly once per trace+compile and never on cached dispatches — the
+    engine passes its retrace counter.
 
     ``axis_names`` defaults to *all* mesh axes — the datastore shards over
     every chip.  Results are fully replicated.  With ``with_stats`` the
-    closure additionally returns the psum-weighted block-prune fraction
-    and the global element-prune fraction (0 unless ``element_stats``).
+    closure also returns the inner loop's raw stats dict, psum-weighted
+    over shards.
 
     Pass ``tree`` (a shard-stacked
     :class:`~repro.search.tree.ShardTreeArrays`, placed like the index) to
     run the per-shard transitive Eq. 13 descent with the broadcast global
-    τ before each shard's leaf scan (DESIGN.md §3.6); with ``with_stats``
-    the closure then also returns the psum-weighted ``tree_prune_frac``
-    and ``tree_node_eval_frac``.
+    τ before each shard's leaf scan (DESIGN.md §3.6); the stats then also
+    hold the psum-weighted ``tree_prune_frac`` and ``tree_node_eval_frac``.
     """
     axis_names = tuple(axis_names or mesh.axis_names)
     mesh = auto_mesh(mesh)
@@ -418,24 +482,17 @@ def make_sharded_search(mesh: Mesh, axis_names: tuple[str, ...] | None = None,
         if trace_hook is not None:
             trace_hook()
         body = functools.partial(
-            sharded_search_local, k=k, axis_names=axis_names, prune=prune,
-            warm_start=warm_start, best_first=best_first,
-            warm_start_blocks=warm_start_blocks,
-            element_stats=element_stats, with_stats=with_stats,
-            margin=margin, n_pivots=n_pivots)
-        n_stats = (6 if tree is not None else 4) if with_stats else 2
-        idx_specs = jax.tree.map(lambda _: P(axis_names), index)
+            sharded_search_local, k=k, axis_names=axis_names,
+            with_stats=with_stats, **search_kw)
+        shards = P(axis_names)
+        idx_specs = jax.tree.map(lambda _: shards, index)
         if tree is None:
-            fn = shard_map(
-                body, mesh=mesh, in_specs=(idx_specs, P()),
-                out_specs=(P(),) * n_stats, check_vma=False)
-            return fn(index, queries)
+            return shard_map(body, mesh=mesh, in_specs=(idx_specs, P()),
+                             out_specs=P(), check_vma=False)(index, queries)
         fn = shard_map(
-            lambda idx, q, tr: body(idx, q, tree=tr),
-            mesh=mesh,
-            in_specs=(idx_specs, P(), jax.tree.map(lambda _: P(axis_names),
-                                                   tree)),
-            out_specs=(P(),) * n_stats, check_vma=False)
+            lambda idx, q, tr: body(idx, q, tree=tr), mesh=mesh,
+            in_specs=(idx_specs, P(), jax.tree.map(lambda _: shards, tree)),
+            out_specs=P(), check_vma=False)
         return fn(index, queries, tree)
 
     return run

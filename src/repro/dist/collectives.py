@@ -19,16 +19,22 @@ def topk_allgather_merge(sims: Array, ids: Array, k: int, axis_names):
     All-gathers the candidate sets over ``axis_names`` (a mesh axis name or
     tuple of names) and re-runs ``top_k`` on the ``[m, shards * k]`` union.
     Exact: every shard's true local top-k is in the union, and the global
-    top-k is a subset of the union of local top-k sets.
+    top-k is a subset of the union of local top-k sets.  The sims travel
+    bit for bit as int32 beside the ids (bools as 0/1), so the exchange is
+    one all-gather.
     """
     axis_names = (axis_names,) if isinstance(axis_names, str) else tuple(axis_names)
-    s = jax.lax.all_gather(sims, axis_names)        # [S, m, k]
-    g = jax.lax.all_gather(ids, axis_names)
-    m = s.shape[1]
+    kk = sims.shape[1]
+    packed = jnp.concatenate(
+        [jax.lax.bitcast_convert_type(sims, jnp.int32),
+         ids.astype(jnp.int32)], axis=1)            # [m, 2k]
+    both = jax.lax.all_gather(packed, axis_names)   # [S, m, 2k]
+    m = both.shape[1]
+    s = jax.lax.bitcast_convert_type(both[..., :kk], sims.dtype)
     s = jnp.moveaxis(s, 0, 1).reshape(m, -1)        # [m, S * k]
-    g = jnp.moveaxis(g, 0, 1).reshape(m, -1)
+    g = jnp.moveaxis(both[..., kk:], 0, 1).reshape(m, -1)
     top_s, pos = jax.lax.top_k(s, k)
-    top_g = jnp.take_along_axis(g, pos, axis=1)
+    top_g = jnp.take_along_axis(g, pos, axis=1).astype(ids.dtype)
     return top_s, top_g
 
 

@@ -1,11 +1,16 @@
 """Search backends: the bare inner loops behind :class:`SearchEngine`.
 
-Every backend implements one method::
+Every backend implements::
 
-    run(engine, queries, k, *, prune, element_stats)
+    make_fused(engine, k, *, prune, element_stats, donate)
+        -> callee(index, queries)
         -> (sims [m, k] f32, ids [m, k] i32 original row ids, raw stats dict)
 
-and registers itself under a name with :func:`register_backend`.  The
+the one jitted dispatch the engine caches per call signature, and all but
+``sharded`` also ``run(engine, queries, k, *, prune, element_stats)``, the
+same search as separate dispatches (the engine's fallback where
+``make_fused`` gives ``None``).  Each registers itself under a name with
+:func:`register_backend`.  The
 engine owns everything else — query normalization, pivot-similarity
 computation, τ warm-start policy, best-first ordering policy, id mapping,
 and :class:`~repro.search.stats.SearchStats` assembly — so a backend is
@@ -13,7 +18,8 @@ only its compute strategy:
 
   ``scan``    pure-JAX ``lax.scan`` over blocks (masked matmuls; portable)
   ``kernel``  fused Pallas kernel (``@pl.when``-skipped tiles; TPU-native)
-  ``sharded`` per-device scan + tiny all-gather top-k merge (mesh required)
+  ``sharded`` each shard's own inner loop + tiny all-gather top-k merge
+              (mesh required)
   ``brute``   full matmul + top-k (baseline / tiny datastores)
 
 The shared helpers here (τ warm-start seeding, best-first block
@@ -322,9 +328,10 @@ def scan_search(
 # ---------------------------------------------------------------------------
 
 def _resolve_bn(index: BlockIndex, bn: int | None) -> int:
-    """Kernel tile size: a multiple of the index block size dividing n_pad."""
-    n_pad = index.db.shape[0]
-    ibs = index.block_size
+    """Kernel tile size: a multiple of the index block size dividing n_pad
+    (of one shard, for a shard-stacked index)."""
+    n_pad = index.db.shape[-2]
+    ibs = n_pad // index.dp_min.shape[-2]
     if bn is None:
         bn = ibs if ibs % 128 == 0 else ibs * max(1, -(-128 // ibs))
     while n_pad % bn or bn % ibs:
@@ -637,15 +644,19 @@ class BruteBackend:
 
 @register_backend("sharded")
 class ShardedBackend:
-    """Mesh-sharded scan + all-gather top-k merge (needs ``mesh``).
+    """Mesh-sharded search: each shard's own inner loop, then the
+    all-gather top-k merge (needs ``mesh``).
 
-    With ``SearchEngine(tree_shards=...)`` enabled, each shard first runs
-    the transitive Eq. 13 descent over its own pivot tree (built lazily
-    here, one tree per shard, placed like the index so every device holds
-    only its own) pruning against the broadcast global τ; the surviving
-    leaves feed the same per-shard scan loop — DESIGN.md §3.6.  The
-    descent runs *inside* ``shard_map`` with fully static shapes, so the
-    whole path stays one jitted unit.
+    Each shard runs the search a one-chip engine would run over it
+    (``SearchEngine.shard_inner``): the fused Pallas kernel search on TPU,
+    brute force on a tiny shard, the scan elsewhere.  With
+    ``SearchEngine(tree_shards=...)`` enabled, each shard instead first
+    runs the transitive Eq. 13 descent over its own pivot tree (built
+    lazily here, one tree per shard, placed like the index so every device
+    holds only its own) pruning against the broadcast global τ; the
+    surviving leaves feed the per-shard scan loop — DESIGN.md §3.6.
+    Everything runs *inside* ``shard_map`` with fully static shapes, so
+    the whole path is one jitted callee.
     """
 
     name = "sharded"
@@ -686,36 +697,32 @@ class ShardedBackend:
             return replicate_to_mesh(_np.asarray(q, _np.float32), eng.mesh)
         return jnp.asarray(q, jnp.float32)
 
-    def run(self, eng, queries, k, *, prune=True, element_stats=False):
+    def make_fused(self, eng, k, *, prune, element_stats, donate):
+        """Query prep, each shard's search, the id map and the merge as one
+        jitted ``shard_map`` callee (``make_sharded_search``)."""
         if eng.mesh is None:
             raise ValueError("the 'sharded' backend needs SearchEngine(mesh=...)")
-        # the descent is pure masking work with prune off: fall back to the
-        # flat per-shard scan, which honors prune=False like every backend
-        use_tree = eng._tree_shards_enabled and prune
-        key = (element_stats, use_tree, prune, eng.n_pivots)
-        fn = eng._sharded_fn.get(key)
-        if fn is None:
-            from repro.core.distributed import make_sharded_search
-            fn = make_sharded_search(
-                eng.mesh, eng.axis_names, with_stats=True, prune=prune,
-                warm_start=eng.warm_start, best_first=eng.best_first,
-                warm_start_blocks=eng.warm_start_blocks,
-                element_stats=element_stats, margin=eng.margin,
-                n_pivots=eng.n_pivots,
-                trace_hook=eng._note_trace)
-            eng._sharded_fn[key] = fn
-        q = self._replicated_queries(eng, queries)
-        if use_tree:
-            s, ids, frac, efrac, tfrac, evfrac = fn(
-                eng.index, q, k, self._shard_tree(eng))
-            raw = {"block_prune_frac": frac, "tree_prune_frac": tfrac,
-                   "tree_node_eval_frac": evfrac}
-        else:
-            s, ids, frac, efrac = fn(eng.index, q, k)
-            raw = {"block_prune_frac": frac}
-        if element_stats:
-            raw["elem_prune_frac"] = efrac
-        return s, ids, raw
+        from repro.core.distributed import make_sharded_search
+        inner = eng.shard_inner(k, prune)
+        use_tree = inner == "tree"
+        fn = make_sharded_search(
+            eng.mesh, eng.axis_names, with_stats=True,
+            trace_hook=eng._note_trace,
+            inner="scan" if use_tree else inner, prune=prune,
+            warm_start=eng.warm_start, best_first=eng.best_first,
+            warm_start_blocks=eng.warm_start_blocks,
+            element_stats=element_stats, margin=eng.margin,
+            n_pivots=eng.n_pivots, bm=eng.bm, bn=eng.bn,
+            sort_queries=eng.sort_queries, interpret=eng.interpret)
+
+        def fused(index, queries):
+            # the tree is fetched PER CALL, like the tree backend's: a
+            # shape-stable mutation swaps in a widened twin of the same
+            # shapes, which reuses the compiled executable
+            tree = self._shard_tree(eng) if use_tree else None
+            return fn(index, self._replicated_queries(eng, queries), k, tree)
+
+        return fused
 
 
 # the tree backend lives in its own module (it is a subsystem, not an inner

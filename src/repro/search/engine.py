@@ -34,7 +34,7 @@ from repro.search import backends as _bk
 from repro.search import defaults as _defaults
 from repro.search.stats import SearchStats
 
-__all__ = ["SearchEngine", "auto_backend"]
+__all__ = ["SearchEngine", "auto_backend", "shard_inner"]
 
 #: below this many padded rows the matmul is cheaper than any bookkeeping
 _BRUTE_MAX_ROWS = 256
@@ -42,6 +42,9 @@ _BRUTE_MAX_ROWS = 256
 #: at this many blocks the flat O(n_blocks) bound pass starts to dominate
 #: and the tree's O(survivors · depth) transitive descent wins
 _TREE_MIN_BLOCKS = 256
+
+#: the widest rows the Pallas kernel holds (its feature dim stays in VMEM)
+_KERNEL_MAX_DIM = 4096
 
 
 def auto_backend(index: BlockIndex, mesh=None) -> str:
@@ -60,13 +63,45 @@ def auto_backend(index: BlockIndex, mesh=None) -> str:
     """
     if index.db.ndim == 3 or mesh is not None:
         return "sharded"
-    n_pad, d = index.db.shape
+    return _flat_backend(*index.db.shape, index.dp_min.shape[-2],
+                         kernel=jax.default_backend() == "tpu")
+
+
+def _flat_backend(n_pad: int, d: int, n_blocks: int, *, kernel: bool) -> str:
     if n_pad <= _BRUTE_MAX_ROWS:
         return "brute"
-    if jax.default_backend() == "tpu" and d <= 4096:
+    if kernel and d <= _KERNEL_MAX_DIM:
         return "kernel"
-    if index.dp_min.shape[-2] >= _TREE_MIN_BLOCKS:
+    if n_blocks >= _TREE_MIN_BLOCKS:
         return "tree"
+    return "scan"
+
+
+def shard_inner(index: BlockIndex, k: int, *, prune: bool = True,
+                tree_shards: bool | None = None,
+                interpret: bool | None = None, bn: int | None = None) -> str:
+    """The search each shard of a shard-stacked ``index`` runs for a call
+    at ``k``: the rule :func:`auto_backend` applies to one flat shard, from
+    the platform and the shard's shape.
+
+    tree    — ``tree_shards=True``, or (``None``) wherever a flat shard
+              would descend a tree: the per-shard descent with its leaf
+              scan (DESIGN.md §3.6); with ``prune=False`` the flat scan;
+    brute   — a tiny shard;
+    kernel  — the fused Pallas kernel search: on TPU, as on one chip, or
+              wherever ``interpret=True`` asks for the interpreted kernel;
+              the scan where ``k`` exceeds the kernel's tile;
+    scan    — everywhere else.
+    """
+    n_pad, d = index.db.shape[-2:]
+    flat = _flat_backend(
+        n_pad, d, index.dp_min.shape[-2],
+        kernel=jax.default_backend() == "tpu" or interpret is True)
+    if prune and (flat == "tree" if tree_shards is None else tree_shards):
+        return "tree"
+    if flat == "brute" or (flat == "kernel"
+                           and k <= _bk._resolve_bn(index, bn)):
+        return flat
     return "scan"
 
 
@@ -116,9 +151,11 @@ class SearchEngine:
         shard over its local pivots) before each shard's leaf scan, with
         the global warm-start τ broadcast into every shard's descent
         (DESIGN.md §3.6).  ``True`` / ``False`` force it; ``None``
-        (default) auto-enables once each shard holds ≥ 256 blocks — the
-        same depth at which the single-device tree backend wins.  Ignored
-        by non-sharded backends (the ``tree`` backend always descends).
+        (default) enables it wherever a flat shard would take the
+        ``tree`` backend (off the TPU, ≥ 256 blocks a shard).  Otherwise
+        each shard runs the inner loop :func:`shard_inner` names (the
+        kernel search on TPU).  Ignored by non-sharded backends (the ``tree``
+        backend always descends).
       n_pivots: joint multi-pivot bound depth (DESIGN.md §3.8): before a
         block is admitted, the ``eq13_multi`` provider intersects the
         classic Eq. 13 interval bound with a joint projection bound over
@@ -138,7 +175,9 @@ class SearchEngine:
         Ignored by non-tree backends.
       bm / bn / sort_queries / interpret: kernel-backend tile options
         (ignored by other backends; ``bm`` / ``interpret`` also apply to
-        the tree backend's kernel leaf stage).
+        the tree backend's kernel leaf stage, and all four to a sharded
+        engine's shards, where ``interpret=True`` runs the kernel
+        interpreted off the TPU too).
     """
 
     def __init__(
@@ -176,7 +215,6 @@ class SearchEngine:
         self.bn = bn
         self.sort_queries = sort_queries
         self.interpret = interpret
-        self._sharded_fn = {}
         self._fn_cache = {}                     # fused dispatch cache
         self._traces = 0                        # jit traces observed, ever
         self._tree_index = None                 # built lazily by TreeBackend
@@ -190,15 +228,8 @@ class SearchEngine:
         self.index_epoch = 0
         self._online = None                     # MutableIndex handle, if any
         self.tree_shards = tree_shards
-        # dp_min is [nb, P] or [S, nb, P] when shard-stacked; the sharded
-        # tree auto-rule looks at the PER-SHARD depth
+        # dp_min is [nb, P] or [S, nb, P] when shard-stacked
         per_shard_blocks = int(index.dp_min.shape[-2])
-        if index.db.ndim == 3:
-            self._tree_shards_enabled = (
-                per_shard_blocks >= _TREE_MIN_BLOCKS
-                if tree_shards is None else bool(tree_shards))
-        else:
-            self._tree_shards_enabled = False
         self.backend_name = (auto_backend(index, mesh)
                              if backend == "auto" else backend)
         # time-tuned per-regime defaults (repro.search.defaults): every
@@ -278,7 +309,11 @@ class SearchEngine:
         Pass ``mesh`` to build a sharded datastore served by the
         ``sharded`` backend: one shard per mesh device, each built on the
         device that holds it.  ``db`` may be a host array or a device
-        array already split by rows over the mesh.
+        array already split by rows over the mesh.  A corpus whose length
+        does not split evenly over the mesh comes as such an array padded
+        to a multiple of the shard count, with ``global_rows`` its true
+        length: the rows at and past ``global_rows`` are padding, marked
+        invalid and never returned.
 
         ``n_pivots`` here is the *index* pivot count (interval tables and
         joint-bound table width); ``bound_pivots`` is the engine's search
@@ -379,7 +414,6 @@ class SearchEngine:
         if shape_changed:
             self.index_epoch += 1
             self._fn_cache.clear()
-            self._sharded_fn.clear()
             self._tree_index = None
             self._tree_valid_nodes = 0
             self._shard_tree = None
@@ -407,6 +441,13 @@ class SearchEngine:
         ``SearchStats.retraces`` and the ``engine.traces`` counter."""
         self._traces += 1
         obs.count("engine.traces")
+
+    def shard_inner(self, k: int, prune: bool = True) -> str:
+        """The search each shard runs for a call at ``k`` (the sharded
+        backend): :func:`shard_inner` over this engine's index and knobs."""
+        return shard_inner(self.index, k, prune=prune,
+                           tree_shards=self.tree_shards,
+                           interpret=self.interpret, bn=self.bn)
 
     def _knob_key(self):
         return (self.warm_start, self.warm_start_blocks, self.best_first,
@@ -487,11 +528,16 @@ class SearchEngine:
         if not hasattr(queries, "shape"):
             queries = jnp.asarray(queries)
         kk = min(k, self.n_slots)
-        # which orientation the kernel reads the stored corpus in
-        layout = ({"db_layout": _bk.cosine_topk.db_layout(
-            self.index.db.shape[-1])} if self.backend_name == "kernel" else {})
+        notes = {}
+        if self.backend_name == "sharded":
+            notes.update(shards=int(self.index.db.shape[0]),
+                         inner=self.shard_inner(kk, prune))
+        if "kernel" in (self.backend_name, notes.get("inner")):
+            # which orientation the kernel reads the stored corpus in
+            notes["db_layout"] = _bk.cosine_topk.db_layout(
+                self.index.db.shape[-1])
         with obs.span("engine.search", backend=self.backend_name, k=k,
-                      m=int(queries.shape[0]), **layout) as call:
+                      m=int(queries.shape[0]), **notes) as call:
             traces_before = self._traces
             with obs.span("engine.dispatch"):
                 fused = self._fused_callable(queries, kk, prune,
@@ -500,14 +546,11 @@ class SearchEngine:
                     sims, ids, raw = fused(queries)
                     retraces = self._traces - traces_before
                 else:
+                    # multi-dispatch (the tree's kernel leaves): unknown
                     sims, ids, raw = self.backend.run(
                         self, queries, kk, prune=prune,
                         element_stats=element_stats)
-                    # the sharded closure carries the trace hook; other
-                    # legacy paths (tree kernel-leaf) are multi-dispatch ->
-                    # unknown
-                    retraces = (self._traces - traces_before
-                                if self.backend_name == "sharded" else None)
+                    retraces = None
             if kk < k:
                 sims, ids = _pad_topk(sims, ids, k=k)
             stats = SearchStats(

@@ -420,7 +420,18 @@ def test_sharded_tree_online_prunes_at_least_flat():
             h.delete(dead)
             assert h._id_pos == hs[0]._id_pos      # identical placement
         stats = [e.search(q, k)[2] for e in engs]
-        flat_blk = float(stats[0].block_prune_frac)
+        # the flat per-shard stage in the tree's unit, (query, index block)
+        # pairs: each shard's scan over the flat engine's mutated index
+        # (its 80-row shards take brute force, which counts no blocks)
+        from repro.core.distributed import make_sharded_search
+        f = engs[0]
+        scan = make_sharded_search(
+            f.mesh, with_stats=True, inner="scan", warm_start=f.warm_start,
+            best_first=f.best_first, warm_start_blocks=f.warm_start_blocks,
+            margin=f.margin, n_pivots=f.n_pivots)
+        flat_blk = float(scan(f.index, jnp.asarray(q), k)[2]
+                         ["block_prune_frac"])
+        assert flat_blk > 0.0, flat_blk
         tree_blk = float(stats[1].block_prune_frac)
         assert stats[1].tree_prune_frac is not None
         assert tree_blk >= flat_blk - 1e-6, (tree_blk, flat_blk)

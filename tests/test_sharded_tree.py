@@ -74,6 +74,9 @@ def test_sharded_tree_prunes_at_least_flat():
     tree = SearchEngine.build(db, n_pivots=8, block_size=32, mesh=mesh,
                               tree_shards=True)
     for k in (8, 48):
+        # both count (query, index block) pairs: the flat side's shards
+        # run the scan, the tree's leaf stage the same scan
+        assert (flat.shard_inner(k), tree.shard_inner(k)) == ("scan", "tree")
         sf, _, stf = flat.search(jnp.asarray(q), k)
         st, _, stt = tree.search(jnp.asarray(q), k)
         np.testing.assert_allclose(np.asarray(st), np.asarray(sf), atol=3e-5)
@@ -95,6 +98,7 @@ def test_sharded_flat_stats_are_psum_weighted():
     from repro.search.backends import prep_queries, scan_search
     eng = SearchEngine.build(db, n_pivots=8, block_size=32, mesh=mesh,
                              tree_shards=False)
+    assert eng.shard_inner(8) == "scan", eng.shard_inner(8)
     _, _, stats = eng.search(jnp.asarray(q), 8, element_stats=True)
     idx = eng.index
     S = idx.db.shape[0]

@@ -19,6 +19,7 @@ import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -187,3 +188,57 @@ def test_fused_kernel_search_reads_the_stored_corpus(d, shape,
         if op == "fusion":
             dims = re.match(r"f32\[([\d,]+)\]", ty).group(1).split(",")
             assert math.prod(map(int, dims)) * 4 <= db_bytes // 8, (name, ty)
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices).reshape(-1), ("data",))
+
+
+def test_four_chip_search_runs_the_kernel_in_every_shard(four_chips,
+                                                         no_persistent_cache):
+    """The sharded engine's one jitted callee over a 2x2 host at the MS
+    MARCO shard shape (2,210,456 rows a chip, padded to 128-row blocks):
+    every chip runs ``pruned_topk`` over its own shard and the answers
+    meet in one all-gather.  No part of the shard is copied per call (the
+    scan inner kept a best-first permutation of the whole shard): the
+    temporaries stay under an eighth of the shard, and the shard feeds
+    only bitcasts, the kernel and fusions at most that size."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.core.distributed import make_sharded_search
+    s, n, d, k = four_chips.devices.size, 17_270 * 128, D, 10
+    stacked = NamedSharding(four_chips, PartitionSpec("data"))
+
+    def leaf(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct((s,) + dims, dtype, sharding=stacked)
+
+    nb = n // 128
+    index = BlockIndex(leaf(n, d), leaf(n, P), leaf(P, d), leaf(nb, P),
+                       leaf(nb, P), leaf(n, dtype=jnp.bool_),
+                       leaf(n, dtype=jnp.int32), leaf(P, d), leaf(n, P),
+                       leaf(n, P))
+    queries = jax.ShapeDtypeStruct(
+        (M, d), jnp.float32,
+        sharding=NamedSharding(four_chips, PartitionSpec()))
+    fn = make_sharded_search(
+        four_chips, with_stats=True, inner="kernel", warm_start=True,
+        best_first=True, n_pivots=0, bm=cosine_topk.DEFAULT_BM, bn=BN,
+        interpret=False)
+    compiled = fn.lower(index, queries, k).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 1
+    assert re.search(r"pruned_topk", text)
+    assert len(re.findall(r"= \S+ all-gather(?:-start)?\(", text)) == 1
+    shard_bytes = n * d * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < shard_bytes // 8, (temp, shard_bytes)
+    consumers = _db_consumers(text, f"f32[1,{n},{d}]")
+    assert [c[1] for c in consumers].count("custom-call") == 1, consumers
+    for name, op, ty in consumers:
+        assert op in ("custom-call", "fusion"), (name, op, ty)
+        if op == "fusion":
+            dims = re.match(r"f32\[([\d,]+)\]", ty).group(1).split(",")
+            assert math.prod(map(int, dims)) * 4 <= shard_bytes // 8, (
+                name, ty)
