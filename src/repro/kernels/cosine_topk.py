@@ -34,6 +34,9 @@ The HBM->VMEM copy of a pruned tile is not yet elided.
 
 Alignment: BM a multiple of 8, BN a multiple of 128 on TPU; D is kept
 whole in VMEM (q tile + db tile at BM=128, BN=256, D=768, f32 = 1.1 MiB).
+``bm`` is the largest query tile: a call of fewer rows runs the same
+number of tiles, each only as tall as its share of the rows needs
+(:func:`query_tile`), so a padded row is never computed for nothing.
 
 Orientation (:func:`db_layout`): the TPU runtime stores an f32 ``[N, D]``
 array column-major when D is not a multiple of 128, so the kernel then
@@ -55,6 +58,7 @@ DEFAULT_BM = 128
 DEFAULT_BN = 256
 _NEG_INF = float("-inf")
 _LANES = 128
+_SUBLANES = 8                                   # f32 rows of one vreg tile
 
 
 def db_layout(d: int) -> str:
@@ -67,6 +71,32 @@ def db_layout(d: int) -> str:
     256, 384, 768 and 1024 row-major).
     """
     return "rows" if d % _LANES == 0 else "cols"
+
+
+def query_tile(m: int, bm: int) -> int:
+    """The query tile's height for a call of ``m`` query rows.
+
+    ``bm`` is the largest tile.  The call runs ``ceil(m / bm)`` tiles, as
+    many as ``bm``-row tiles would take, of ``round_up(ceil(m / tiles), 8)``
+    rows (8 is the f32 sublane tile), never more than ``bm``: a 64-row call
+    at ``bm=128`` runs one 64-row tile, a 256-row call two 128-row tiles.
+    """
+    tiles = max(1, -(-m // bm))
+    rows = -(-m // tiles)
+    return min(bm, max(_SUBLANES, -(-rows // _SUBLANES) * _SUBLANES))
+
+
+def tile_order(ub: Array, bm: int) -> Array:
+    """Best-first ``block_order`` for :func:`pruned_topk`: each query
+    tile's db tiles by descending tile bound, the max of ``ub`` [m, nt]
+    over the tile's rows (:func:`query_tile` rows each) -> [tiles, nt] i32.
+    """
+    m, nt = ub.shape
+    rows = query_tile(m, bm)
+    mp = -(-m // rows) * rows
+    ub_p = jnp.pad(ub, ((0, mp - m), (0, 0)), constant_values=_NEG_INF)
+    tile_ub = ub_p.reshape(mp // rows, rows, nt).max(axis=1)
+    return jnp.argsort(-tile_ub, axis=1).astype(jnp.int32)
 
 
 def _make_kernel(k: int, bm: int, bn: int, margin: float, prune: bool,
@@ -270,6 +300,8 @@ def pruned_topk(
                DESIGN.md §3.9): the kernel masks scores per ROW, so
                validity need not be a prefix.
       k:       top-k (k <= bn).
+      bm:      the largest query tile; the kernel runs ``ceil(M / bm)``
+               tiles of :func:`query_tile` rows.
       element_stats: also count, per visited tile, the (query, row) pairs
                whose individual Eq. 13 bound is below the running τ — the
                backend-uniform ``elem_prune_frac`` numerator.
@@ -294,6 +326,7 @@ def pruned_topk(
     if element_stats and dp is None:
         raise ValueError("element_stats=True requires dp ([N, P] per-row "
                          "pivot similarities)")
+    bm = query_tile(m, bm)
     mp = -(-m // bm) * bm
     nt = n // bn
     kp = -(-k // _LANES) * _LANES                  # lane-dense top-k slots

@@ -74,7 +74,6 @@ def gathered_topk(
     """
     nb, bs = index.n_blocks, index.block_size
     d = index.db.shape[1]
-    m = qn.shape[0]
     assert k <= bs, "kernel leaf stage needs k <= block_size"
 
     db_c = index.db.reshape(nb, bs, d)[keep].reshape(n_keep * bs, d)
@@ -86,10 +85,7 @@ def gathered_topk(
     block_order = None
     if best_first:
         ub = kref.block_bounds(qp, lo_c, hi_c)                 # [m, n_keep]
-        mp = -(-m // bm) * bm
-        ub_p = jnp.pad(ub, ((0, mp - m), (0, 0)), constant_values=-jnp.inf)
-        tile_ub = ub_p.reshape(mp // bm, bm, n_keep).max(axis=1)
-        block_order = jnp.argsort(-tile_ub, axis=1).astype(jnp.int32)
+        block_order = cosine_topk.tile_order(ub, bm)
 
     dp_c = None
     if element_stats:

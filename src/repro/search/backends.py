@@ -428,12 +428,7 @@ def kernel_search(
     block_order = None
     if best_first:
         with jax.named_scope("order"):
-            mp = -(-m // bm) * bm
-            nt = lo.shape[0]
-            ub_p = jnp.pad(ub, ((0, mp - m), (0, 0)),
-                           constant_values=-jnp.inf)
-            tile_ub = ub_p.reshape(mp // bm, bm, nt).max(axis=1)
-            block_order = jnp.argsort(-tile_ub, axis=1).astype(jnp.int32)
+            block_order = cosine_topk.tile_order(ub, bm)
 
     with jax.named_scope("pruned_topk"):
         sims, pos, computed, elem, rounds = cosine_topk.pruned_topk(

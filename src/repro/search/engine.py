@@ -177,7 +177,11 @@ class SearchEngine:
         (ignored by other backends; ``bm`` / ``interpret`` also apply to
         the tree backend's kernel leaf stage, and all four to a sharded
         engine's shards, where ``interpret=True`` runs the kernel
-        interpreted off the TPU too).
+        interpreted off the TPU too).  ``bm`` is the largest query tile:
+        a call of ``m`` rows runs ``ceil(m / bm)`` tiles of
+        ``round_up(ceil(m / tiles), 8)`` rows
+        (:func:`repro.kernels.cosine_topk.query_tile`), so a 64-query
+        batch at ``bm=128`` runs one 64-row tile.
     """
 
     def __init__(
@@ -532,12 +536,15 @@ class SearchEngine:
         if self.backend_name == "sharded":
             notes.update(shards=int(self.index.db.shape[0]),
                          inner=self.shard_inner(kk, prune))
+        m = int(queries.shape[0])
         if "kernel" in (self.backend_name, notes.get("inner")):
-            # which orientation the kernel reads the stored corpus in
+            # which orientation the kernel reads the stored corpus in, and
+            # the query tile's height it runs for this batch
             notes["db_layout"] = _bk.cosine_topk.db_layout(
                 self.index.db.shape[-1])
+            notes["bm"] = _bk.cosine_topk.query_tile(m, self.bm)
         with obs.span("engine.search", backend=self.backend_name, k=k,
-                      m=int(queries.shape[0]), **notes) as call:
+                      m=m, **notes) as call:
             traces_before = self._traces
             with obs.span("engine.dispatch"):
                 fused = self._fused_callable(queries, kk, prune,
@@ -555,7 +562,7 @@ class SearchEngine:
                 sims, ids = _pad_topk(sims, ids, k=k)
             stats = SearchStats(
                 backend=self.backend_name,
-                n_queries=int(queries.shape[0]),
+                n_queries=m,
                 k=k,
                 n_blocks=self.n_blocks,
                 block_prune_frac=raw.get("block_prune_frac", 0.0),

@@ -7,7 +7,8 @@ from repro.core import ref as cref
 from repro.core.index import build_index
 from repro.kernels import ref as kref
 from repro.kernels.bound_prune import block_bounds as bp_kernel
-from repro.kernels.cosine_topk import db_layout, pruned_topk
+from repro.kernels.cosine_topk import (db_layout, pruned_topk, query_tile,
+                                      tile_order)
 from repro.kernels.tile_prescan import tau_prescan
 from repro.search.backends import (kernel_search, map_row_ids, prep_queries,
                                    tau_warm_start)
@@ -273,3 +274,72 @@ def test_column_layout_is_exact(d, k, dead, rng):
     np.testing.assert_allclose(s_cols, out["rows"][0], rtol=0, atol=1e-6)
     np.testing.assert_array_equal(np.sort(i_cols, 1), np.sort(ref_i, 1))
     np.testing.assert_allclose(s_cols, ref_s, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("bm", [32, 128])
+@pytest.mark.parametrize("m", [1, 5, 64, 100, 130, 256])
+def test_query_tile_follows_the_batch(m, bm, rng):
+    """``bm`` is the largest query tile: a call of ``m`` rows runs
+    ``ceil(m / bm)`` tiles of ``round_up(ceil(m / tiles), 8)`` rows.  The
+    kernel, called directly and through ``kernel_search``, returns brute
+    force's answers with the tile count's shapes; at m=64, bm=128 it
+    returns what the same call with the queries padded by hand to one
+    128-row tile returns, ids and counters alike (the sims within f32
+    rounding: the CPU's dot may block a different M differently)."""
+    tiles = -(-m // bm)
+    rows = query_tile(m, bm)
+    want = {(256, 128): 128, (64, 128): 64, (130, 128): 72, (5, 128): 8}
+    assert rows == want.get((m, bm), rows)
+    assert rows % 8 == 0 and rows <= bm and (tiles - 1) * rows < m <= (
+        tiles * rows)
+
+    n, d, k, bn, p = 1024, 16, 10, 128, 8
+    nt = n // bn
+    db = clustered(rng, n, d)
+    q = cref.normalize(db[rng.choice(n, m)]
+                       + 0.02 * rng.normal(size=(m, d))).astype(np.float32)
+    ref = q.astype(np.float64) @ db.T.astype(np.float64)
+    ref_i = np.sort(np.argsort(-ref, axis=1)[:, :k], 1)
+    ref_s = -np.sort(-ref, axis=1)[:, :k]
+
+    idx = build_index(jnp.asarray(db), n_pivots=p, block_size=128)
+    qn, qp = prep_queries(idx, jnp.asarray(q))
+    s, pos, computed, _, rounds = kernel_search(
+        idx, qn, qp, k, bm=bm, bn=bn, warm_start=True, best_first=True)
+    assert computed.shape == (tiles, nt) and rounds.shape == (tiles,)
+    np.testing.assert_allclose(np.asarray(s), ref_s, rtol=0, atol=1e-5)
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(map_row_ids(idx.row_ids, pos)), 1), ref_i)
+
+    # the raw kernel over hand-built intervals, τ seeds and best-first order
+    piv = db[:p]
+    qp, dp = jnp.asarray(q @ piv.T), db @ piv.T
+    lo = jnp.asarray(dp.reshape(nt, bn, p).min(1))
+    hi = jnp.asarray(dp.reshape(nt, bn, p).max(1))
+    qn, dbj = jnp.asarray(q), jnp.asarray(db)
+    ub = kref.block_bounds(qp, lo, hi)
+    tau = tau_warm_start(qn, dbj.reshape(nt, bn, d),
+                         jnp.ones((nt, bn), bool), ub, k, 1)
+    order = tile_order(ub, bm)
+    assert order.shape == (tiles, nt)
+    out = pruned_topk(qn, dbj, qp, lo, hi, n, tau_init=tau,
+                      block_order=order, k=k, bm=bm, bn=bn, interpret=True)
+    s, i, computed, _, rounds = map(np.asarray, out)
+    assert computed.shape == (tiles, nt) and rounds.shape == (tiles,)
+    np.testing.assert_allclose(s, ref_s, rtol=0, atol=1e-5)
+    np.testing.assert_array_equal(np.sort(i, 1), ref_i)
+
+    if (m, bm) == (64, 128):
+        def pad(a, v):
+            return jnp.pad(a, ((0, 128 - m),) + ((0, 0),) * (a.ndim - 1),
+                           constant_values=v)
+        whole = pruned_topk(
+            pad(qn, 0.0), dbj, pad(qp, 1.0), lo, hi, n, m_valid=m,
+            tau_init=pad(tau, -np.inf),
+            block_order=tile_order(pad(ub, -np.inf), 128), k=k, bm=128,
+            bn=bn, interpret=True)
+        s_w, i_w, computed_w, _, rounds_w = map(np.asarray, whole)
+        np.testing.assert_array_equal(i, i_w[:m])
+        np.testing.assert_array_equal(computed, computed_w)
+        np.testing.assert_array_equal(rounds, rounds_w)
+        np.testing.assert_allclose(s, s_w[:m], rtol=0, atol=1e-6)

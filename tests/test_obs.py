@@ -119,6 +119,25 @@ def test_engine_search_names_the_kernels_db_layout(backend, d, want, rng,
     assert [st.get("db_layout") for st in stats] == [want]
 
 
+@pytest.mark.parametrize("backend,m,want", [("kernel", 4, 8),
+                                             ("kernel", 64, 64),
+                                             ("kernel", 130, 72),
+                                             ("scan", 64, None)])
+def test_engine_search_names_the_kernels_query_tile(backend, m, want, rng,
+                                                    tmp_path):
+    """``engine.search`` carries ``bm``, the height of the query tile the
+    kernel ran for the call's ``m`` rows: at the engine's ``bm`` of 128, one
+    8-row tile for 4 queries, one 64-row tile for 64, two 72-row tiles for
+    130.  Other backends give no ``bm``."""
+    eng = _engine(rng, backend=backend)
+    q = jnp.asarray(np.asarray(eng.index.db[:m]))
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(eng.search(q, 5)[:2])
+    (search,) = obs.records("engine.search")
+    assert search.ids["m"] == m
+    assert search.ids.get("bm") == want
+
+
 def test_engine_traces_counts_one_trace_per_new_shape(rng):
     eng = _engine(rng)
     q = jnp.asarray(np.asarray(eng.index.db[:6]))

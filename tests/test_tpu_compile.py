@@ -152,8 +152,38 @@ def _db_consumers(hlo: str, db_shape: str) -> list[tuple[str, str, str]]:
     return out
 
 
+@pytest.fixture(scope="module")
+def fused_search(one_chip):
+    """``compiled(m, d)``: the engine's one-dispatch kernel search, as the
+    engine builds it with its defaults, compiled for ``m`` queries over
+    2^20 rows of width ``d`` (once per shape, for every test of the file).
+    """
+    done = {}
+
+    def compiled(m, d):
+        if (m, d) not in done:
+            def shape(*dims, dtype=jnp.float32):
+                return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+            n, p, bs, k = 1 << 20, P, 128, 10
+            eng = types.SimpleNamespace(
+                _note_trace=lambda: None, bm=cosine_topk.DEFAULT_BM, bn=BN,
+                sort_queries=True, warm_start=True, best_first=True,
+                margin=4e-7, interpret=False, warm_start_blocks=None,
+                n_pivots=0)
+            fused = get_backend("kernel").make_fused(
+                eng, k, prune=True, element_stats=False, donate=False)
+            nb = n // bs
+            index = BlockIndex(shape(n, d), shape(n, p), shape(p, d),
+                               shape(nb, p), shape(nb, p),
+                               shape(n, dtype=jnp.bool_),
+                               shape(n, dtype=jnp.int32))
+            done[m, d] = fused.lower(index, shape(m, d)).compile()
+        return done[m, d]
+    return compiled
+
+
 @pytest.mark.parametrize("d", [96, 768])
-def test_fused_kernel_search_reads_the_stored_corpus(d, shape,
+def test_fused_kernel_search_reads_the_stored_corpus(d, fused_search,
                                                      no_persistent_cache):
     """The engine's one-dispatch kernel search (query prep, bound, τ
     prescan, best-first order, ``pruned_topk``, id map), as the engine
@@ -165,18 +195,8 @@ def test_fused_kernel_search_reads_the_stored_corpus(d, shape,
     the XLA prescan gathers ``m`` 256-row tiles (``[m, 256, d]``); with
     2^20 rows that is an eighth of the corpus at m=256, so no temporary
     reaches it."""
-    n, p, bs, k = 1 << 20, P, 128, 10
-    eng = types.SimpleNamespace(
-        _note_trace=lambda: None, bm=cosine_topk.DEFAULT_BM, bn=BN,
-        sort_queries=True, warm_start=True, best_first=True, margin=4e-7,
-        interpret=False, warm_start_blocks=None, n_pivots=0)
-    fused = get_backend("kernel").make_fused(
-        eng, k, prune=True, element_stats=False, donate=False)
-    nb = n // bs
-    index = BlockIndex(shape(n, d), shape(n, p), shape(p, d), shape(nb, p),
-                       shape(nb, p), shape(n, dtype=jnp.bool_),
-                       shape(n, dtype=jnp.int32))
-    compiled = fused.lower(index, shape(M, d)).compile()
+    n = 1 << 20
+    compiled = fused_search(M, d)
     db_bytes = n * d * 4
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < db_bytes // 8, (temp, db_bytes)
@@ -188,6 +208,29 @@ def test_fused_kernel_search_reads_the_stored_corpus(d, shape,
         if op == "fusion":
             dims = re.match(r"f32\[([\d,]+)\]", ty).group(1).split(",")
             assert math.prod(map(int, dims)) * 4 <= db_bytes // 8, (name, ty)
+
+
+@pytest.mark.parametrize("m,d", [(64, 768), (256, 768), (256, 96)])
+def test_fused_kernel_search_sizes_the_query_tile_to_the_batch(
+        m, d, fused_search, no_persistent_cache):
+    """``bm`` (128) is the largest query tile: a served 64-query microbatch
+    reaches ``pruned_topk`` as ``f32[64, d]`` and runs one 64-row tile,
+    with nothing padding it to 128 rows; a 256-query batch runs two
+    128-row tiles, at d=768 (rows) and d=96 (read as ``db.T``).  The
+    kernel's results say the grid: the top-k block is ``[tiles x rows,
+    128]`` and the ``computed`` counter ``[tiles, 4096]``."""
+    tiles, rows = -(-m // cosine_topk.DEFAULT_BM), min(m, 128)
+    text = fused_search(m, d).as_text()
+    (call,) = re.findall(r"%pruned_topk[\w.]* = \((.*?)\) custom-call\("
+                         r".*?operand_layout_constraints=\{(.*?)\}, \w+=", text)
+    results = re.findall(r"[fs]32\[[\d,]*\]", call[0])
+    operands = re.findall(r"[fs]32\[[\d,]*\]", call[1])
+    # block_order, m_valid, τ seeds, then the queries
+    assert operands[:4] == [f"s32[{tiles},4096]", "s32[1]",
+                            f"f32[{tiles * rows},1]", f"f32[{m},{d}]"]
+    assert results == [f"f32[{tiles * rows},128]", f"s32[{tiles * rows},128]",
+                       f"s32[{tiles},4096]", f"s32[{tiles}]"]
+    assert f"f32[{cosine_topk.DEFAULT_BM},{d}]" not in text or m > 128
 
 
 @pytest.fixture(scope="module")
